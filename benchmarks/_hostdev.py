@@ -12,6 +12,10 @@ core, so these runs measure collective/dispatch OVERHEAD, not speedup.
 ``BENCH_JSON:{...}`` payload printed by the snippet (None on failure,
 with stderr forwarded — benchmarks degrade gracefully, they don't
 crash the harness).
+
+These children time XLA's CPU backend. In a parent that holds a TPU they
+would report CPU numbers inside a chip run, and a child could not reach
+the chip anyway, so ``run_hostdev`` refuses there.
 """
 from __future__ import annotations
 
@@ -28,6 +32,14 @@ JSON_TAG = "BENCH_JSON:"
 def run_hostdev(code: str, n_devices: int, *, timeout: int = 900,
                 check: bool = True) -> subprocess.CompletedProcess:
     """Run ``code`` in a subprocess with ``n_devices`` forced host devices."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "run_hostdev times forced CPU host devices in a child process; "
+            "this parent holds a TPU, so the numbers would be CPU numbers "
+            "in a chip run. Run the multi-device path in this process on "
+            "the chip's own devices instead.")
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count="

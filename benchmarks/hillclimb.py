@@ -31,7 +31,6 @@ def measure(arch: str, shape_name: str, overrides: dict, mesh_name="pod1"):
     from repro.launch import dryrun
     from repro.launch.specs import abstract_model, param_bytes
     from repro.parallel.mesh import make_production_mesh
-    from repro import compat
 
     shape = next(s for s in ALL_SHAPES if s.name == shape_name)
     cfg = get_config(arch)
@@ -47,7 +46,7 @@ def measure(arch: str, shape_name: str, overrides: dict, mesh_name="pod1"):
             sub["n_enc_layers"] = L
         cfg_l = dataclasses.replace(cfg, **sub)
         t0 = time.time()
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn, args = dryrun.build_step(cfg_l, shape, mesh,
                                          force_param_bytes=full_pbytes)
             compiled = fn.lower(*args).compile()

@@ -28,7 +28,6 @@ def main():
     import dataclasses
     import jax
     from repro.configs import ALL_SHAPES, get_config
-    from repro import compat
     from repro.launch.specs import abstract_model, param_bytes
     from repro.parallel.mesh import make_production_mesh
 
@@ -41,7 +40,7 @@ def main():
         sub["n_enc_layers"] = args.layers
     cfg_l = dataclasses.replace(cfg, **sub)
     mesh = make_production_mesh(multi_pod=(args.mesh == "pod2"))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, fargs = dryrun.build_step(cfg_l, shape, mesh,
                                       force_param_bytes=full_pbytes)
         hlo = fn.lower(*fargs).compile().as_text()

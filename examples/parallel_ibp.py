@@ -1,27 +1,28 @@
 """Distributed IBP inference over a real JAX mesh (shard_map + psum).
 
-Relaunches itself with 8 forced host devices, builds a ('data',) mesh, and
-runs the hybrid sampler with X and Z physically sharded across devices —
-the production code path that runs unchanged on a TPU pod (launch/mesh.py
-builds the (data, model) / (pod, data, model) meshes).
+On the CPU it relaunches itself with 8 forced host devices; on a TPU it
+uses the chips it has. It builds a ('data',) mesh with one shard per
+device and runs the hybrid sampler with X and Z physically sharded
+across devices — the production code path.
 
     PYTHONPATH=src python examples/parallel_ibp.py
 """
 import os
 import sys
 
-if "XLA_FLAGS" not in os.environ:  # relaunch with 8 virtual devices
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    os.execv(sys.executable, [sys.executable] + sys.argv)
-
 import jax
 import jax.numpy as jnp
+
+if jax.default_backend() == "cpu" and "XLA_FLAGS" not in os.environ:
+    # relaunch with 8 virtual devices
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.execv(sys.executable, [sys.executable] + sys.argv)
 
 from repro.core.ibp import IBPHypers, SamplerSpec, build_sampler
 from repro.core.ibp.diagnostics import train_joint_loglik
 from repro.data import cambridge_data
 
-N, Pn, K_max, K_tail = 320, 8, 16, 6
+N, Pn, K_max, K_tail = 320, jax.device_count(), 16, 6
 print(f"devices: {jax.device_count()} | observations: {N} over P={Pn} shards")
 
 X, _, _ = cambridge_data(N=N, sigma_n=0.5, seed=1)

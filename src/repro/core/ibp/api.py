@@ -43,6 +43,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from .collapsed import COLLAPSED_BACKENDS, DEFAULT_REFRESH, K_LIVE_MODES
 from .hybrid import (
@@ -246,8 +247,6 @@ class Sampler:
 
     # ---- construction helpers --------------------------------------------
     def _make_mesh(self):
-        from repro.compat import make_mesh
-
         spec = self.spec
         if spec.data != "shardmap" and spec.chains != "mesh":
             return None
@@ -261,10 +260,13 @@ class Sampler:
                 f"--xla_force_host_platform_device_count on CPU)"
             )
         if spec.chains == "mesh" and spec.data == "shardmap":
-            return make_mesh((spec.n_chains, spec.P), ("chains", "data"))
-        if spec.chains == "mesh":
-            return make_mesh((spec.n_chains,), ("chains",))
-        return make_mesh((spec.P,), ("data",))
+            shape, names = (spec.n_chains, spec.P), ("chains", "data")
+        elif spec.chains == "mesh":
+            shape, names = (spec.n_chains,), ("chains",)
+        else:
+            shape, names = (spec.P,), ("data",)
+        return jax.make_mesh(shape, names,
+                             axis_types=(AxisType.Auto,) * len(names))
 
     def _shardings(self):
         """(data-rows, chains, chains x data-rows) NamedShardings."""

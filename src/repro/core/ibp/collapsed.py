@@ -122,7 +122,7 @@ def _sample_dishes(kdish, q, mean, x_n, active_m, z, alpha, sx, sa, N, D,
     lam = alpha / N
     s = 1.0 + q
     r = x_n - mean
-    rss = jnp.dot(r, r)
+    rss = ibm.dot(r, r)
     js = jnp.arange(J_MAX + 1, dtype=x_n.dtype)
     rho = (sa / sx) ** 2
     s_j = s + js * rho
@@ -184,10 +184,10 @@ def _row_step(carry, n, *, X, N, D, birth="gibbs"):
     W = ibm.padded_W(ZtZ_m, active_m, ratio)
     M, _ = ibm.chol_inv_logdet(W)
     M = M * ibm.mask_outer(active_m)
-    H = M @ (ZtX_m * active_m[:, None])  # (K, D) posterior mean map
-    v = M @ z
-    q = jnp.dot(z, v)
-    mean = z @ H
+    H = ibm.dot(M, ZtX_m * active_m[:, None])  # (K, D) posterior mean map
+    v = ibm.dot(M, z)
+    q = ibm.dot(z, v)
+    mean = ibm.dot(z, H)
     inv2s2 = 0.5 / (sx**2)
 
     K = Z.shape[1]
@@ -219,7 +219,7 @@ def _exact_factor(ZtZ, ZtX, active, ratio):
     W = ibm.padded_W(ZtZ, active, ratio)
     L, M = ibm.chol_inv(W)
     M = M * ibm.mask_outer(active)
-    H = M @ (ZtX * active[:, None])
+    H = ibm.dot(M, ZtX * active[:, None])
     return L.T, M, H
 
 
@@ -304,7 +304,7 @@ def _packed_scan(
     # the mean-form pallas flip never consumes G — skip the whole G carry
     # (moves, refresh rebuild, probe term) at trace time for that flavor
     carry_g = carry_g and flip_flavor != "pallas"
-    G0 = H0 @ H0.T if carry_g else jnp.zeros((), X.dtype)
+    G0 = ibm.dot(H0, H0.T) if carry_g else jnp.zeros((), X.dtype)
     inv2s2 = 0.5 / (sx**2)
 
     # ---- hoist the oracle's per-row PRNG out of the serial loop: the
@@ -372,12 +372,12 @@ def _packed_scan(
         # the packed block — see that function for the algebra notes)
         m_minus = m - z_old
         zu = z_old * active
-        w = M @ zu
-        p_down = Lt @ w
+        w = ibm.dot(M, zu)
+        p_down = ibm.dot(Lt, w)
         down_ok = jnp.all(1.0 - jnp.cumsum(p_down * p_down) > 1e-12)
-        gamma = jnp.dot(zu, w)
+        gamma = ibm.dot(zu, w)
         delta_s = jnp.maximum(1.0 - gamma, 1e-6)
-        zH = zu @ H
+        zH = ibm.dot(zu, H)
         wr = w / jnp.sqrt(delta_s)
         wd = w / delta_s
         b_rm = zH - x_n
@@ -404,12 +404,13 @@ def _packed_scan(
         # G-consistency residual ‖G p − H(Hᵀp)‖∞ (relative to max|G|) so
         # the carried G is covered by the same monitor (DESIGN.md §14)
         def do_probe(_):
-            tm = ZtZ @ active_m - z_old * jnp.dot(z_old, active_m)
+            tm = ibm.dot(ZtZ, active_m) - z_old * ibm.dot(z_old, active_m)
             probe_t = active_m * tm + ratio * active_m
-            d_m = jnp.max(jnp.abs(M1 @ probe_t - active_m))
+            d_m = jnp.max(jnp.abs(ibm.dot(M1, probe_t) - active_m))
             if not carry_g:
                 return d_m
-            d_g = jnp.max(jnp.abs(G1 @ active_m - H1 @ (active_m @ H1)))
+            d_g = jnp.max(jnp.abs(ibm.dot(G1, active_m)
+                                  - ibm.dot(H1, ibm.dot(active_m, H1))))
             d_g = d_g / (1.0 + jnp.max(jnp.abs(G1)))
             return jnp.maximum(d_m, d_g)
 
@@ -425,8 +426,8 @@ def _packed_scan(
             ZtX_m = ZtX - jnp.outer(z_old, x_n)
             L2, M2 = ibm.chol_inv(ibm.padded_W(ZtZ_m, active_m, ratio))
             M2 = M2 * ibm.mask_outer(active_m)
-            H2 = M2 @ (ZtX_m * active_m[:, None])
-            return L2.T, M2, H2, (H2 @ H2.T if carry_g else G)
+            H2 = ibm.dot(M2, ZtX_m * active_m[:, None])
+            return L2.T, M2, H2, (ibm.dot(H2, H2.T) if carry_g else G)
 
         Lt_rm, M1, H1, G1 = jax.lax.cond(
             need, do_refresh, lambda _: (Lt, M1, H1, G1), None
@@ -459,8 +460,8 @@ def _packed_scan(
             return wd, gd, zH + gd * (zH - x_n)
 
         def vqm_matvec(_):
-            v = M1 @ z
-            return v, jnp.dot(z, v), z @ H1
+            v = ibm.dot(M1, z)
+            return v, ibm.dot(z, v), ibm.dot(z, H1)
 
         v, q, mean = jax.lax.cond(
             has_drop | need, vqm_matvec, vqm_closed, None
@@ -526,12 +527,12 @@ def _packed_scan(
                 has_drop | jnp.any(newbits > 0.5), diag_swaps,
                 lambda ops: ops, (Lt1, M1, H1, G1),
             )
-            w2 = M1b @ z2
-            Lt2 = ibm.chol_rank1_update_t(Lt1, Lt1 @ w2)
-            d2 = 1.0 + jnp.dot(z2, w2)
+            w2 = ibm.dot(M1b, z2)
+            Lt2 = ibm.chol_rank1_update_t(Lt1, ibm.dot(Lt1, w2))
+            d2 = 1.0 + ibm.dot(z2, w2)
             w2r = w2 / jnp.sqrt(d2)
             M2 = M1b - jnp.outer(w2r, w2r)
-            b_add = x_n - z2 @ H1b
+            b_add = x_n - ibm.dot(z2, H1b)
             H2 = H1b + jnp.outer(w2 / d2, b_add)
             G2 = ibm.g_rank1(G1b, H1b, w2 / d2, b_add) if carry_g else G1b
             return Lt2, M2, H2, G2
@@ -744,8 +745,8 @@ def _collapsed_sweep_jit(
 def _sweep_stats(Z, active, X):
     """Exact sweep-entry sufficient statistics (+ K⁺ for bucket choice)."""
     m = jnp.sum(Z * active[None, :], axis=0)
-    ZtZ = (Z.T @ Z) * ibm.mask_outer(active)
-    ZtX = (Z.T @ X) * active[:, None]
+    ZtZ = ibm.dot(Z.T, Z) * ibm.mask_outer(active)
+    ZtX = ibm.dot(Z.T, X) * active[:, None]
     return m, ZtZ, ZtX, jnp.sum(active)
 
 

@@ -39,8 +39,6 @@ off the spec and returns jitted ``(step, stale)`` functions for the
 requested layout — the old per-backend entry points
 (``hybrid_iteration_vmap`` / ``_multichain`` / ``hybrid_stale_pass`` /
 ``make_hybrid_iteration_shardmap``) are subsumed by spec layouts.
-Mesh construction and shard_map go through ``repro.compat`` so the same
-code runs on JAX 0.4.x and on the modern AxisType/set_mesh API.
 
 The ``stale`` function is the bounded-staleness knob (DESIGN.md §10):
 sub-iterations only, no master sync (and, on a mesh, no collectives at
@@ -59,8 +57,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-from repro import compat
 
 from . import math as ibm
 from .collapsed import DEFAULT_REFRESH, collapsed_row_scan
@@ -645,7 +641,7 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
 
             def block_stale(X_p, gs, Z_p, Zt_p, ta_p):
                 ta = ta_p[0]
-                idx = compat.axis_index(data_axes)
+                idx = jax.lax.axis_index(data_axes)
                 gs_sweep = dataclasses.replace(
                     gs, key=jax.random.fold_in(gs.key, 13)
                 )
@@ -660,7 +656,7 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
 
             def block_staged(X_p, gs, Z_p, Zt_p, ta_p):
                 ta = ta_p[0]  # (1, K_tail) local block -> (K_tail,)
-                idx = compat.axis_index(data_axes)
+                idx = jax.lax.axis_index(data_axes)
                 Z_p, Zt_p2, ta, n_sat = shard_sub_iterations(
                     X_p, Z_p, Zt_p, ta, gs, idx, N_g, L, be, cb, cr, pk
                 )
@@ -680,7 +676,7 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
 
             def block_fused(X_p, gs, Z_p, Zt_p, ta_p):
                 ta = ta_p[0]
-                idx = compat.axis_index(data_axes)
+                idx = jax.lax.axis_index(data_axes)
                 Z_p, Zt_p2, ta, n_sat = shard_sub_iterations(
                     X_p, Z_p, Zt_p, ta, gs, idx, N_g, L, be, cb, cr, pk
                 )
@@ -766,7 +762,7 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
             else:
                 x_spec, g_leaf, z_spec = P(d_ent), P(), P(d_ent)
             gspec = jax.tree.map(lambda _: g_leaf, gs)
-            return compat.shard_map(
+            return jax.shard_map(
                 shard_fn,
                 mesh=mesh,
                 in_specs=(x_spec, gspec, z_spec, z_spec, z_spec),

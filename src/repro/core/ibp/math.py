@@ -8,6 +8,13 @@ mask (float {0,1}) selects live columns.  Inactive rows/cols are arranged so
 that padded linear algebra (Cholesky of W) is exact: the padded W gets unit
 diagonal / zero off-diagonal in inactive slots, contributing 0 to logdet and
 nothing to the trace term.
+
+Every f32 product of the collapsed sampler goes through ``dot`` below, at
+``precision="highest"`` (the collapsed_row kernels pass it too). A TPU
+otherwise runs it as one bf16 pass, about three significant digits: on
+a v5e the carried Cholesky factor and inverse then drift past the
+monitor's tolerance (87 refreshes where the cadence schedules 15, at
+N=1000) and the fast chain leaves the ref oracle (DESIGN.md §12).
 """
 from __future__ import annotations
 
@@ -17,6 +24,11 @@ import jax.numpy as jnp
 Array = jax.Array
 
 LOG2PI = float(jnp.log(2.0 * jnp.pi))
+
+
+def dot(a: Array, b: Array) -> Array:
+    """``jnp.dot`` at f32 precision (see the module docstring)."""
+    return jnp.dot(a, b, precision="highest")
 
 
 def mask_outer(active: Array) -> Array:
@@ -43,7 +55,7 @@ def chol_inv_logdet(W: Array) -> tuple[Array, Array]:
     logdet = 2.0 * jnp.sum(jnp.log(jnp.diagonal(L)))
     eye = jnp.eye(W.shape[0], dtype=W.dtype)
     Linv = jax.scipy.linalg.solve_triangular(L, eye, lower=True)
-    Winv = Linv.T @ Linv
+    Winv = dot(Linv.T, Linv)
     return Winv, logdet
 
 
@@ -53,7 +65,7 @@ def chol_inv(W: Array) -> tuple[Array, Array]:
     L = jnp.linalg.cholesky(W)
     eye = jnp.eye(W.shape[0], dtype=W.dtype)
     Linv = jax.scipy.linalg.solve_triangular(L, eye, lower=True)
-    return L, Linv.T @ Linv
+    return L, dot(Linv.T, Linv)
 
 
 def collapsed_loglik(
@@ -79,7 +91,7 @@ def collapsed_loglik(
     W = padded_W(ZtZ, active, ratio)
     M, logdetW = chol_inv_logdet(W)
     ZtX_m = ZtX * active[:, None]
-    quad = jnp.sum((M @ ZtX_m) * ZtX_m)  # tr( (ZtX)^T M (ZtX) )
+    quad = jnp.sum(dot(M, ZtX_m) * ZtX_m)  # tr( (ZtX)^T M (ZtX) )
     Nf = N.astype(jnp.float32) if hasattr(N, "astype") else jnp.float32(N)
     return (
         -0.5 * Nf * D * LOG2PI
@@ -95,15 +107,15 @@ def sm_downdate(M: Array, z: Array) -> tuple[Array, Array]:
 
     Returns (M', log det(W - z z^T) - log det W) = (M', log(1 - z^T M z)).
     """
-    Mz = M @ z
-    denom = 1.0 - jnp.dot(z, Mz)
+    Mz = dot(M, z)
+    denom = 1.0 - dot(z, Mz)
     return M + jnp.outer(Mz, Mz) / denom, jnp.log(denom)
 
 
 def sm_update(M: Array, z: Array) -> tuple[Array, Array]:
     """Sherman-Morrison addition: M' = (W + z z^T)^{-1}; logdet delta = log(1+z^T M z)."""
-    Mz = M @ z
-    denom = 1.0 + jnp.dot(z, Mz)
+    Mz = dot(M, z)
+    denom = 1.0 + dot(z, Mz)
     return M - jnp.outer(Mz, Mz) / denom, jnp.log(denom)
 
 
@@ -150,7 +162,7 @@ def _chol_rank1_t(Lt: Array, p: Array, sigma: float, eps: float) -> tuple[Array,
     # ones matrix rather than jnp.cumsum: on CPU/TPU the K^3 matmul beats
     # the K^2 scan-lowered cumsum by ~2x at our K (BLAS/MXU vs serial scan)
     tril = jnp.tril(jnp.ones((K, K), Lt.dtype))
-    acc = tril @ Gt
+    acc = dot(tril, Gt)
     Ct = acc[-1][None, :] - acc
     return Lt * r[:, None] + Ct * qc[:, None], ok
 
@@ -216,7 +228,7 @@ def g_rank1(G: Array, H: Array, a: Array, b: Array) -> Array:
     (callers mask the rank-one vector), so row/col j of every correction
     term is exactly 0 — padding-transparent, like the chol moves.
     """
-    c = H @ b + (0.5 * jnp.dot(b, b)) * a
+    c = dot(H, b) + (0.5 * dot(b, b)) * a
     return G + (jnp.outer(a, c) + jnp.outer(c, a))
 
 

@@ -492,13 +492,11 @@ def make_sharded_scorer(bank: SampleBank, mesh, *, axis: str = "data",
     size)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     def block(X_p, key):
-        k = jax.random.fold_in(key, compat.axis_index((axis,)))
+        k = jax.random.fold_in(key, jax.lax.axis_index(axis))
         return predictive_loglik(bank, X_p, k, n_sweeps=n_sweeps)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         block, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(axis),
         check_vma=False,
     )

@@ -18,6 +18,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from . import math as ibm
+
 Array = jax.Array
 
 
@@ -55,7 +57,7 @@ def _uncollapsed_sweep_jnp(
     key: Array,
 ) -> Array:
     N, K = Z.shape
-    R = X - Z @ A                      # residual under current Z
+    R = X - ibm.dot(Z, A)              # residual under current Z
     anorm2 = jnp.sum(A * A, axis=1)    # (K,)
     lpi = _logit(pi)
     # pre-drawn uniforms, in logit space so the accept test is logit > u
@@ -69,7 +71,7 @@ def _uncollapsed_sweep_jnp(
         # residual with Z_nk = 0
         R0 = R + z_k[:, None] * a_k[None, :]
         # loglik(z=1) - loglik(z=0) = (2 R0·a_k - |a_k|^2) / (2 sigma^2)
-        dll = (2.0 * (R0 @ a_k) - anorm2[k]) * inv2s2
+        dll = (2.0 * ibm.dot(R0, a_k) - anorm2[k]) * inv2s2
         logits = lpi[k] + dll
         znew = jnp.where(active[k] > 0, (logits > u[:, k]).astype(Z.dtype), z_k)
         R = R0 - znew[:, None] * a_k[None, :]

@@ -55,10 +55,10 @@ def collapsed_row_flip_fast(
     K = z.shape[0]
     D = x_n.shape[0]
     if G is None:
-        G = H @ H.T
+        G = jnp.dot(H, H.T, precision="highest")
     r = x_n - mean
-    rss = jnp.dot(r, r)
-    rH = H @ r
+    rss = jnp.dot(r, r, precision="highest")
+    rH = jnp.dot(H, r, precision="highest")
     logprior = jnp.log(jnp.maximum(m_minus, 1e-20)) - jnp.log(N - m_minus)
     ks = jnp.nonzero(active_m > 0.5, size=K, fill_value=0)[0]
     n_act = jnp.sum(active_m > 0.5).astype(jnp.int32)
@@ -98,4 +98,4 @@ def collapsed_row_flip_fast(
     _, z, v, q, rss, rH = jax.lax.while_loop(
         lambda c: c[0] < n_act, body, c0
     )
-    return z, v, q, z @ H
+    return z, v, q, jnp.dot(z, H, precision="highest")

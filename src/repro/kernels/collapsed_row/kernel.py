@@ -53,8 +53,11 @@ def _kernel(mt_ref, h_ref, x_ref, z_ref, v_ref, q_ref, mean_ref, u_ref,
     def body(k, carry):
         z, v, q, mean = carry
         onehot = (kidx == k).astype(jnp.float32)              # (1, K)
-        Mk = jnp.dot(onehot, Mt, preferred_element_type=jnp.float32)  # (1, K) = M[:, k]
-        Hk = jnp.dot(onehot, H, preferred_element_type=jnp.float32)   # (1, D)
+        # exact selections: a TPU's default bf16 pass would round M, H
+        Mk = jnp.dot(onehot, Mt, precision="highest",
+                     preferred_element_type=jnp.float32)  # (1, K) = M[:, k]
+        Hk = jnp.dot(onehot, H, precision="highest",
+                     preferred_element_type=jnp.float32)  # (1, D)
         Mkk = jnp.sum(Mk * onehot)
         zk = jnp.sum(z * onehot)
         vk = jnp.sum(v * onehot)
@@ -90,7 +93,7 @@ def _kernel(mt_ref, h_ref, x_ref, z_ref, v_ref, q_ref, mean_ref, u_ref,
     z, v, q, mean = jax.lax.fori_loop(0, K, body, (z, v, q, mean))
     zout_ref[...] = z
     vout_ref[...] = v
-    qout_ref[0, 0] = q
+    qout_ref[...] = jnp.reshape(q, (1, 1))  # Mosaic stores no VMEM scalar
     meanout_ref[...] = mean
 
 

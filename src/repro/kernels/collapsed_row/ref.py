@@ -61,8 +61,10 @@ def collapsed_row_flip_ref(
         s1 = 1.0 + q1
         r0 = x_n - mean0
         r1 = x_n - mean1
-        ll0 = -0.5 * D * jnp.log(s0) - inv2s2 * jnp.dot(r0, r0) / s0
-        ll1 = -0.5 * D * jnp.log(s1) - inv2s2 * jnp.dot(r1, r1) / s1
+        rss0 = jnp.dot(r0, r0, precision="highest")
+        rss1 = jnp.dot(r1, r1, precision="highest")
+        ll0 = -0.5 * D * jnp.log(s0) - inv2s2 * rss0 / s0
+        ll1 = -0.5 * D * jnp.log(s1) - inv2s2 * rss1 / s1
         mk = m_minus[k]
         logodds = jnp.log(jnp.maximum(mk, 1e-20)) - jnp.log(N - mk) + ll1 - ll0
         # sample; only live columns with support may flip

@@ -6,13 +6,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels._backend import default_interpret
+
 from .kernel import DEFAULT_BLOCK_N, feature_stats_pallas
 
 Array = jax.Array
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -31,4 +29,5 @@ def feature_stats_core(
 def feature_stats(
     X: Array, Z: Array, block_n: int = DEFAULT_BLOCK_N
 ) -> tuple[Array, Array, Array]:
-    return feature_stats_core(X, Z, block_n=block_n, interpret=not _on_tpu())
+    return feature_stats_core(X, Z, block_n=block_n,
+                              interpret=default_interpret())

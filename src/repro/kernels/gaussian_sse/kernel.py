@@ -3,7 +3,9 @@
 sigma_x's posterior needs ||X - Z A||^2 right after the master A draw. The
 naive lowering materializes the (N_p, D) residual in HBM (write + re-read);
 this kernel fuses (mask -> matmul -> subtract -> square -> reduce) per VMEM
-block and accumulates a single f32 scalar across the grid.
+block and accumulates into one (1, 1) f32 block across the grid. The
+accumulation is a (1, 1) vector store: Mosaic cannot store a scalar to
+VMEM.
 """
 from __future__ import annotations
 
@@ -22,7 +24,7 @@ def _kernel(x_ref, z_ref, a_ref, act_ref, out_ref):
     xb = x_ref[...]                       # (BN, D)
     zb = z_ref[...] * act_ref[...]        # (BN, K) masked
     r = xb - jnp.dot(zb, a_ref[...], preferred_element_type=jnp.float32)
-    out_ref[0, 0] += jnp.sum(r * r)
+    out_ref[...] += jnp.sum(r * r, keepdims=True)
 
 
 def gaussian_sse_pallas(

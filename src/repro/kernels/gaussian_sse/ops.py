@@ -6,13 +6,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels._backend import default_interpret
+
 from .kernel import DEFAULT_BLOCK_N, gaussian_sse_pallas
 
 Array = jax.Array
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -36,4 +34,5 @@ def gaussian_sse_core(
 def gaussian_sse(
     X: Array, Z: Array, A: Array, active: Array, block_n: int = DEFAULT_BLOCK_N
 ) -> Array:
-    return gaussian_sse_core(X, Z, A, active, block_n=block_n, interpret=not _on_tpu())
+    return gaussian_sse_core(X, Z, A, active, block_n=block_n,
+                             interpret=default_interpret())

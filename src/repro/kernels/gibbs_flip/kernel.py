@@ -37,13 +37,17 @@ def _kernel(x_ref, z_ref, a_ref, lpi_ref, act_ref, anorm_ref, u_ref, s_ref,
     inv2s2 = s_ref[0, 0]      # scalar
 
     K = z.shape[1]
-    res = x - jnp.dot(z, A, preferred_element_type=jnp.float32)
+    # f32 products: a TPU's default is one bf16 pass, which would round
+    # the one-hot row selections below and the residual
+    res = x - jnp.dot(z, A, precision="highest",
+                      preferred_element_type=jnp.float32)
     kidx = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
 
     def body(k, carry):
         res, z = carry
         onehot = (kidx == k).astype(jnp.float32)          # (1, K)
-        a_k = jnp.dot(onehot, A, preferred_element_type=jnp.float32)  # (1, D)
+        a_k = jnp.dot(onehot, A, precision="highest",
+                      preferred_element_type=jnp.float32)  # (1, D)
         z_k = jnp.sum(z * onehot, axis=1)                 # (BN,)
         u_k = jnp.sum(u * onehot, axis=1)                 # (BN,)
         anorm_k = jnp.sum(anorm * onehot)

@@ -22,7 +22,7 @@ def gibbs_flip_ref(
     u_logit: Array,  # (N, K) logit-uniforms
     inv2s2: Array,   # () = 1 / (2 sigma_x^2)
 ) -> Array:
-    R = X - Z @ A
+    R = X - jnp.dot(Z, A, precision="highest")
     anorm2 = jnp.sum(A * A, axis=1)
 
     def body(carry, k):
@@ -30,7 +30,8 @@ def gibbs_flip_ref(
         a_k = A[k]
         z_k = Z[:, k]
         R0 = R + z_k[:, None] * a_k[None, :]
-        dll = (2.0 * (R0 @ a_k) - anorm2[k]) * inv2s2
+        s = jnp.dot(R0, a_k, precision="highest")
+        dll = (2.0 * s - anorm2[k]) * inv2s2
         logits = logit_pi[k] + dll
         znew = jnp.where(active[k] > 0, (logits > u_logit[:, k]).astype(Z.dtype), z_k)
         R = R0 - znew[:, None] * a_k[None, :]

@@ -26,8 +26,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from repro.configs import ALL_SHAPES, ARCH_IDS, get_config, shape_applicable
 from repro.launch.specs import (
     abstract_caches,
@@ -194,7 +192,7 @@ def run_cell(arch: str, shape, mesh_name: str, force: bool = False) -> dict:
     mesh = make_production_mesh(multi_pod=(mesh_name == "pod2"))
     t0 = time.time()
     try:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn, args = build_step(cfg, shape, mesh)
             lowered = fn.lower(*args)
             t_lower = time.time() - t0
@@ -266,7 +264,7 @@ def run_ibp_cell(mesh_name: str, *, N: int = 1 << 20, D: int = 36,
     }
     t0 = time.time()
     try:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             # every production mesh axis is a data axis here (flattened
             # into the paper's P processors); no chain axis in this cell
             spec = SamplerSpec(P=P_, L=L, K_max=K_max, K_tail=K_tail,
@@ -379,7 +377,7 @@ def run_probe(arch: str, shape, mesh_name: str, force: bool = False) -> dict:
             if cfg.family == "encdec":
                 sub["n_enc_layers"] = L
             cfg_l = dataclasses.replace(cfg, **sub)
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 fn, args = build_step(
                     cfg_l, shape, mesh, force_param_bytes=full_pbytes
                 )

@@ -53,6 +53,7 @@ from repro.core.ibp import IBPHypers, SamplerSpec
 from repro.core.ibp.api import DRIVERS
 from repro.core.ibp.collapsed import DEFAULT_REFRESH
 from repro.data import cambridge_data, train_eval_split
+from repro.launch.jax_cache import enable_compile_cache
 from repro.runtime import MCMCDriver
 
 
@@ -119,6 +120,7 @@ def main(argv=None):
                          "<ckpt-dir>/bank.npz)")
     ap.add_argument("--out", default="artifacts/mcmc_history.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     X, Ztrue, Atrue = cambridge_data(N=args.N, sigma_n=args.sigma_n,
                                      seed=args.seed)

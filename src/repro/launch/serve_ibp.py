@@ -52,9 +52,7 @@ import numpy as np
 
 from repro.core.ibp import predict
 from repro.core.ibp import math as ibm
-
-REPO_ROOT = os.path.dirname(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+from repro.launch.jax_cache import REPO_ROOT, enable_compile_cache
 
 OPS = ("loglik", "anomaly", "encode", "impute")
 
@@ -209,6 +207,19 @@ def serve(bank, reqs, op: str, batch: int, n_sweeps: int, seed: int):
     return responses, stats
 
 
+def check_responses(reqs, responses, op: str) -> None:
+    """One finite response per request, with the request's row count."""
+    if len(responses) != len(reqs):
+        raise AssertionError(f"{len(reqs) - len(responses)} responses lost")
+    for (rows, _), resp in zip(reqs, responses):
+        n = rows.shape[0]
+        got = resp.shape[-2] if op == "encode" else resp.shape[0]
+        if got != n:
+            raise AssertionError(f"response rows {got} != request rows {n}")
+        if not np.all(np.isfinite(np.asarray(resp))):
+            raise AssertionError(f"non-finite {op} scores")
+
+
 def merge_bench_json(stats: dict, path: str) -> str:
     """Append the serving stats into BENCH_<date>.json via the shared
     tolerant atomic merge (``checkpoint.update_json`` — the same
@@ -246,6 +257,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes + sanity assertions (CI fast gate)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         args.requests = min(args.requests, 8)
@@ -268,12 +280,7 @@ def main(argv=None):
           f"(warmup {stats['warmup_s']:.1f}s)")
 
     if args.smoke:
-        assert len(responses) == len(reqs), "lost responses"
-        for (rows, _), resp in zip(reqs, responses):
-            n = rows.shape[0]
-            got = resp.shape[-2] if args.op == "encode" else resp.shape[0]
-            assert got == n, f"response rows {got} != request rows {n}"
-            assert np.all(np.isfinite(np.asarray(resp))), "non-finite scores"
+        check_responses(reqs, responses, args.op)
         print("smoke OK")
 
     if args.bench_json != "none":

@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 Array = jax.Array
 
 # logical axis placeholders; parallel/mesh.py maps them to mesh axes
@@ -113,7 +111,7 @@ def sp_out_proj(h: Array, w: Array, specs, fallback_spec) -> Array:
         y = jnp.einsum("bsf,fd->bsd", h_loc, w_loc)   # partial sum over f
         return jax.lax.psum_scatter(y, tp, scatter_dimension=1, tiled=True)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(bdim, None, tp), P(tp, None)),
@@ -131,7 +129,7 @@ def maybe_shard(x: Array, spec) -> Array:
     """
     if spec is None or not isinstance(spec, P) or all(e is None for e in spec):
         return x
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return x
     fixed = []

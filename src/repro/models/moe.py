@@ -35,8 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from .modules import FSDP, TP, linear_init, maybe_shard
 
 Array = jax.Array
@@ -209,7 +207,7 @@ def _moe_a2a(p: dict, x: Array, cfg, specs) -> tuple[Array, Array]:
         )[:T]
         return y.reshape(Bl, Sl, d), aux
 
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(x_spec, P(), P(tp, None, None), P(tp, None, None)),

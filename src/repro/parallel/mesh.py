@@ -24,10 +24,9 @@ from typing import Any
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import AxisType, make_mesh
 from repro.models.modules import FSDP, TP
 from repro.models.transformer import ActSpecs
 
@@ -38,7 +37,7 @@ SERVE_WEIGHT_BUDGET = 9 * 1024**3  # leave headroom for caches/activations
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(
+    return jax.make_mesh(
         shape, axes, axis_types=(AxisType.Auto,) * len(axes)
     )
 

@@ -492,6 +492,11 @@ class MCMCDriver:
     def evaluate(self, gs: HybridGlobal, ss: HybridShard, it: int,
                  elapsed: float) -> dict[str, Any]:
         X = jnp.asarray(self.X_global)
+        # the held-out scorer runs the gaussian_sse kernel outside any
+        # shard_map, and a compiled Pallas kernel cannot be partitioned
+        # over a mesh: it scores one device's copy of the parameters
+        gs_ev = (gs if self.sampler.mesh is None
+                 else jax.device_put(gs, jax.devices()[0]))
         if self._chain_axis:
             C = ss.Z.shape[0]
             Z = ss.Z.reshape(C, self.N, -1)
@@ -520,7 +525,7 @@ class MCMCDriver:
                         self.X_eval, A, pi, act, sx,
                         jax.random.fold_in(k, 999),
                     )
-                )(gs.A, gs.pi, gs.active, gs.sigma_x, gs.key)
+                )(gs_ev.A, gs_ev.pi, gs_ev.active, gs_ev.sigma_x, gs_ev.key)
                 rec["joint_ll_eval"] = float(jnp.mean(ev))
         else:
             Z = ss.Z.reshape(self.N, -1)
@@ -538,8 +543,8 @@ class MCMCDriver:
             }
             if self.X_eval is not None:
                 rec["joint_ll_eval"] = float(heldout_joint_loglik(
-                    self.X_eval, gs.A, gs.pi, gs.active, gs.sigma_x,
-                    jax.random.fold_in(gs.key, 999),
+                    self.X_eval, gs_ev.A, gs_ev.pi, gs_ev.active,
+                    gs_ev.sigma_x, jax.random.fold_in(gs_ev.key, 999),
                 ))
         rec.update(self.diagnostics())
         return rec

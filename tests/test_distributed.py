@@ -116,7 +116,8 @@ def test_moe_a2a_matches_gather_dispatch():
     out = run_with_devices("""
         import dataclasses, numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import AxisType, make_mesh, set_mesh
+        from jax.sharding import AxisType
+        from jax import make_mesh, set_mesh
         from repro.configs import get_config
         from repro.models import init_model, ActSpecs
         from repro.models.moe import moe_apply
@@ -166,7 +167,8 @@ def test_lm_train_step_shards_on_8_devices():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType, make_mesh, set_mesh
+        from jax.sharding import AxisType
+        from jax import make_mesh, set_mesh
         from repro.configs import get_config
         from repro.models import init_model, make_train_step
         from repro.models.transformer import ActSpecs

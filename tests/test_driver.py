@@ -20,15 +20,25 @@ def data():
     return X
 
 
-def test_kmax_overflow_checkpoints_then_grows(data, tmp_path):
+def test_kmax_overflow_checkpoints_then_grows(tmp_path):
     """Feature-slot overflow checkpoints + raises; restarting with a larger
     K_max pads the checkpointed feature axis and resumes (never silent
     truncation) — DESIGN.md §10."""
+    # six strong planted features against K_max=2, with sigma_x held at
+    # the true noise so extra variance cannot absorb them: the tail
+    # births features the instantiated block has no slot for within the
+    # first few iterations, on any PRNG stream
+    rng = np.random.default_rng(0)
+    Zt = (rng.random((48, 6)) < 0.5).astype(np.float32)
+    At = 4.0 * rng.standard_normal((6, 36)).astype(np.float32)
+    data = Zt @ At + 0.3 * rng.standard_normal((48, 36)).astype(np.float32)
+    hyp = IBPHypers(resample_sigmas=False)
     cfg = DriverConfig(P=3, K_max=2, K_tail=2, K_init=1, L=3, n_iters=40,
+                      sigma_x=0.3, sigma_a=4.0,
                       ckpt_every=1000, eval_every=1000,
                       ckpt_dir=str(tmp_path))
     with pytest.raises(RuntimeError, match="overflow"):
-        MCMCDriver(data, cfg, IBPHypers()).run()
+        MCMCDriver(data, cfg, hyp).run()
     step = latest_step(str(tmp_path))
     assert step is not None  # overflow wrote a checkpoint first
 
@@ -38,7 +48,7 @@ def test_kmax_overflow_checkpoints_then_grows(data, tmp_path):
         K *= 2
         try:
             gs, ss = MCMCDriver(
-                data, dataclasses.replace(cfg, K_max=K), IBPHypers()
+                data, dataclasses.replace(cfg, K_max=K), hyp
             ).run()
             break
         except RuntimeError:

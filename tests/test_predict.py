@@ -171,9 +171,7 @@ def test_empty_builder_build_raises():
 def test_rows_joint_loglik_matches_numpy_oracle_1e6():
     """The jitted batched scorer's per-row joint ll (and its logsumexp
     mixture) match the explicit float64 numpy loop to 1e-6."""
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(9)
         S, K, D, B = 3, 6, 7, 4
         bank = make_bank(S=S, K_max=8, K_live=5, D=D, seed=9)
@@ -320,11 +318,9 @@ def test_same_driver_rerun_does_not_duplicate_harvests(tmp_path):
 
 
 def test_sharded_scorer_matches_unsharded():
-    from repro.compat import make_mesh
-
     bank = make_bank(S=2, K_max=8, K_live=3, D=6, seed=15)
     X = np.random.default_rng(16).normal(size=(6, 6)).astype(np.float32)
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     score = predict.make_sharded_scorer(bank, mesh, n_sweeps=3)
     key = jax.random.key(7)
     got = np.asarray(score(jnp.asarray(X), key))
