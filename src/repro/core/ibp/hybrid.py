@@ -44,6 +44,12 @@ The ``stale`` function is the bounded-staleness knob (DESIGN.md §10):
 sub-iterations only, no master sync (and, on a mesh, no collectives at
 all) — explicitly non-exact.
 
+Every layout runs the step's three layers under named scopes, so a
+profiler trace can split the step (DESIGN.md §16): ``ibp_sweep`` (the
+uncollapsed sweep, ``sweeps.uncollapsed_sweep``), ``ibp_tail`` (the
+collapsed tail, ``_tail_sub_iteration``) and ``ibp_sync`` (everything
+after the sub-iterations: promotion, statistics, the master's draws).
+
 Most callers want the higher-level ``build_sampler`` (core/ibp/api.py),
 which wraps these functions in a uniform init/step/stale/to_canonical
 protocol and owns mesh creation + data placement.
@@ -185,23 +191,24 @@ def _tail_sub_iteration(
     accepted MH birth was vetoed purely by K_tail capacity — the tail-
     saturation signal driving adaptive K_tail growth.
     """
-    # residual given instantiated features = the tail model's data
-    R = X_p - (Z * gs.active[None, :]) @ gs.A
-    m_t = jnp.sum(Z_tail, axis=0)
-    ZtZ_t = Z_tail.T @ Z_tail
-    ZtR = Z_tail.T @ R
-    # u_chunk_rows=n_rows: this entry is vmapped (chains/shards) — the
-    # chunked refill would lower to select and regenerate per row
-    Z_tail, tail_active, _, _, m_t, _, n_sat = collapsed_row_scan(
-        Z_tail, tail_active, ZtZ_t, ZtR, m_t, R, key,
-        gs.alpha, gs.sigma_x, gs.sigma_a,
-        N=N_global, birth="mh", backend=collapsed_backend,
-        refresh_every=chol_refresh, pack=k_live_pack,
-        u_chunk_rows=R.shape[0],
-    )
-    # prune dead tail columns
-    tail_active = tail_active * (m_t > 0.5)
-    Z_tail = Z_tail * tail_active[None, :]
+    with jax.named_scope("ibp_tail"):
+        # residual given instantiated features = the tail model's data
+        R = X_p - (Z * gs.active[None, :]) @ gs.A
+        m_t = jnp.sum(Z_tail, axis=0)
+        ZtZ_t = Z_tail.T @ Z_tail
+        ZtR = Z_tail.T @ R
+        # u_chunk_rows=n_rows: this entry is vmapped (chains/shards) — the
+        # chunked refill would lower to select and regenerate per row
+        Z_tail, tail_active, _, _, m_t, _, n_sat = collapsed_row_scan(
+            Z_tail, tail_active, ZtZ_t, ZtR, m_t, R, key,
+            gs.alpha, gs.sigma_x, gs.sigma_a,
+            N=N_global, birth="mh", backend=collapsed_backend,
+            refresh_every=chol_refresh, pack=k_live_pack,
+            u_chunk_rows=R.shape[0],
+        )
+        # prune dead tail columns
+        tail_active = tail_active * (m_t > 0.5)
+        Z_tail = Z_tail * tail_active[None, :]
     return Z_tail, tail_active, n_sat
 
 
@@ -392,41 +399,42 @@ def _hybrid_iteration_body(
     Z, Z_tail, tail_active, n_sat = jax.vmap(
         sub, in_axes=(0, 0, 0, 0, None, 0)
     )(X_shards, ss.Z, ss.Z_tail, ss.tail_active, gs, jnp.arange(P_))
-    n_sat = jnp.sum(n_sat)  # only p' contributes
+    with jax.named_scope("ibp_sync"):
+        n_sat = jnp.sum(n_sat)  # only p' contributes
 
-    # ---- master sync (simulated psum = sum over shard axis)
-    tail_g = jnp.sum(tail_active, axis=0)  # only p' is nonzero
-    Z, active_new, n_drop = jax.vmap(
-        promote_tail, in_axes=(0, 0, None, None)
-    )(Z, Z_tail, tail_g, gs.active)
-    active_new = active_new[0]  # identical across shards
-    n_drop = n_drop[0]
+        # ---- master sync (simulated psum = sum over shard axis)
+        tail_g = jnp.sum(tail_active, axis=0)  # only p' is nonzero
+        Z, active_new, n_drop = jax.vmap(
+            promote_tail, in_axes=(0, 0, None, None)
+        )(Z, Z_tail, tail_g, gs.active)
+        active_new = active_new[0]  # identical across shards
+        n_drop = n_drop[0]
 
-    stats = jax.vmap(local_stats)(X_shards, Z)
-    stats = jax.tree.map(lambda x: jnp.sum(x, axis=0), stats)
-    A, pi, active, m = master_step1(stats, active_new, gs, N_g, D)
-    Z = Z * active[None, None, :]
+        stats = jax.vmap(local_stats)(X_shards, Z)
+        stats = jax.tree.map(lambda x: jnp.sum(x, axis=0), stats)
+        A, pi, active, m = master_step1(stats, active_new, gs, N_g, D)
+        Z = Z * active[None, None, :]
 
-    sse = jnp.sum(jax.vmap(local_sse, in_axes=(0, 0, None, None))(
-        X_shards, Z, A, active
-    ))
-    sigma_x, sigma_a, alpha, p_prime = master_step2(
-        sse, A, active, gs, hyp, N_g, D, P_
-    )
+        sse = jnp.sum(jax.vmap(local_sse, in_axes=(0, 0, None, None))(
+            X_shards, Z, A, active
+        ))
+        sigma_x, sigma_a, alpha, p_prime = master_step2(
+            sse, A, active, gs, hyp, N_g, D, P_
+        )
 
-    gs_new = HybridGlobal(
-        A=A, pi=pi, active=active, alpha=alpha,
-        sigma_x=sigma_x, sigma_a=sigma_a,
-        key=jax.random.fold_in(gs.key, 7),
-        p_prime=p_prime, it=gs.it + 1,
-        overflow=gs.overflow + n_drop,
-        tail_sat=gs.tail_sat + n_sat,
-    )
-    ss_new = HybridShard(
-        Z=Z,
-        Z_tail=jnp.zeros_like(ss.Z_tail),
-        tail_active=jnp.zeros_like(ss.tail_active),
-    )
+        gs_new = HybridGlobal(
+            A=A, pi=pi, active=active, alpha=alpha,
+            sigma_x=sigma_x, sigma_a=sigma_a,
+            key=jax.random.fold_in(gs.key, 7),
+            p_prime=p_prime, it=gs.it + 1,
+            overflow=gs.overflow + n_drop,
+            tail_sat=gs.tail_sat + n_sat,
+        )
+        ss_new = HybridShard(
+            Z=Z,
+            Z_tail=jnp.zeros_like(ss.Z_tail),
+            tail_active=jnp.zeros_like(ss.tail_active),
+        )
     return gs_new, ss_new
 
 
@@ -660,18 +668,20 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                 Z_p, Zt_p2, ta, n_sat = shard_sub_iterations(
                     X_p, Z_p, Zt_p, ta, gs, idx, N_g, L, be, cb, cr, pk
                 )
-                tail_g, n_sat_g = jax.lax.psum((ta, n_sat), data_axes)  # AR 1
-                Z_p, active_new, n_drop = promote_tail(Z_p, Zt_p2, tail_g,
-                                                       gs.active)
-                stats = local_stats(X_p, Z_p)
-                stats = jax.lax.psum(stats, data_axes)              # AR 2
-                A, pi, active, m = master_step1(stats, active_new, gs,
-                                                N_g, D)
-                Z_p = Z_p * active[None, :]
-                sse = jax.lax.psum(                                  # AR 3
-                    local_sse(X_p, Z_p, A, active), data_axes)
-                gs_new, Zt0, ta0 = finish(gs, A, pi, active, sse, n_drop,
-                                          n_sat_g, Zt_p, ta_p)
+                with jax.named_scope("ibp_sync"):
+                    tail_g, n_sat_g = jax.lax.psum((ta, n_sat),  # AR 1
+                                                   data_axes)
+                    Z_p, active_new, n_drop = promote_tail(Z_p, Zt_p2, tail_g,
+                                                           gs.active)
+                    stats = local_stats(X_p, Z_p)
+                    stats = jax.lax.psum(stats, data_axes)              # AR 2
+                    A, pi, active, m = master_step1(stats, active_new, gs,
+                                                    N_g, D)
+                    Z_p = Z_p * active[None, :]
+                    sse = jax.lax.psum(                                  # AR 3
+                        local_sse(X_p, Z_p, A, active), data_axes)
+                    gs_new, Zt0, ta0 = finish(gs, A, pi, active, sse, n_drop,
+                                              n_sat_g, Zt_p, ta_p)
                 return gs_new, Z_p, Zt0, ta0
 
             def block_fused(X_p, gs, Z_p, Zt_p, ta_p):
@@ -680,45 +690,47 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                 Z_p, Zt_p2, ta, n_sat = shard_sub_iterations(
                     X_p, Z_p, Zt_p, ta, gs, idx, N_g, L, be, cb, cr, pk
                 )
-                K_max = Z_p.shape[1]
-                K_tail = ta.shape[0]
-                # local stats WITH own tail pre-scattered (non-p' adds
-                # zeros; p' uses the same deterministic slot assignment
-                # every shard re-derives after the reduce)
-                Z_stats, _, _ = promote_tail(Z_p, Zt_p2, ta, gs.active)
-                stats = local_stats(X_p, Z_stats)
-                # the saturation count rides the single payload as a
-                # float scalar (small exact integers — f32-exact)
-                payload = jnp.concatenate([
-                    stats["ZtZ"].reshape(-1),
-                    stats["ZtX"].reshape(-1),
-                    stats["m"],
-                    ta,
-                    jnp.sum(X_p * X_p)[None],
-                    n_sat.astype(X_p.dtype)[None],
-                ])
-                g = jax.lax.psum(payload, data_axes)                # AR (only)
-                o1 = K_max * K_max
-                o2 = o1 + K_max * X_p.shape[1]
-                ZtZ = g[:o1].reshape(K_max, K_max)
-                ZtX = g[o1:o2].reshape(K_max, X_p.shape[1])
-                m_g = g[o2:o2 + K_max]
-                tail_g = g[o2 + K_max:o2 + K_max + K_tail]
-                xx = g[-2]
-                n_sat_g = g[-1].astype(jnp.int32)
-                Z_p, active_new, n_drop = promote_tail(Z_p, Zt_p2, tail_g,
-                                                       gs.active)
-                A, pi, active, m = master_step1(
-                    {"m": m_g, "ZtZ": ZtZ, "ZtX": ZtX}, active_new, gs,
-                    N_g, D
-                )
-                Z_p = Z_p * active[None, :]
-                # SSE identity — exact, no second reduction
-                ZtXm = ZtX * active[:, None]
-                ZtZm = ZtZ * ibm.mask_outer(active)
-                sse = xx - 2.0 * jnp.sum(A * ZtXm) + jnp.sum(A * (ZtZm @ A))
-                gs_new, Zt0, ta0 = finish(gs, A, pi, active, sse, n_drop,
-                                          n_sat_g, Zt_p, ta_p)
+                with jax.named_scope("ibp_sync"):
+                    K_max = Z_p.shape[1]
+                    K_tail = ta.shape[0]
+                    # local stats WITH own tail pre-scattered (non-p' adds
+                    # zeros; p' uses the same deterministic slot assignment
+                    # every shard re-derives after the reduce)
+                    Z_stats, _, _ = promote_tail(Z_p, Zt_p2, ta, gs.active)
+                    stats = local_stats(X_p, Z_stats)
+                    # the saturation count rides the single payload as a
+                    # float scalar (small exact integers — f32-exact)
+                    payload = jnp.concatenate([
+                        stats["ZtZ"].reshape(-1),
+                        stats["ZtX"].reshape(-1),
+                        stats["m"],
+                        ta,
+                        jnp.sum(X_p * X_p)[None],
+                        n_sat.astype(X_p.dtype)[None],
+                    ])
+                    g = jax.lax.psum(payload, data_axes)    # AR (only)
+                    o1 = K_max * K_max
+                    o2 = o1 + K_max * X_p.shape[1]
+                    ZtZ = g[:o1].reshape(K_max, K_max)
+                    ZtX = g[o1:o2].reshape(K_max, X_p.shape[1])
+                    m_g = g[o2:o2 + K_max]
+                    tail_g = g[o2 + K_max:o2 + K_max + K_tail]
+                    xx = g[-2]
+                    n_sat_g = g[-1].astype(jnp.int32)
+                    Z_p, active_new, n_drop = promote_tail(Z_p, Zt_p2, tail_g,
+                                                           gs.active)
+                    A, pi, active, m = master_step1(
+                        {"m": m_g, "ZtZ": ZtZ, "ZtX": ZtX}, active_new, gs,
+                        N_g, D
+                    )
+                    Z_p = Z_p * active[None, :]
+                    # SSE identity — exact, no second reduction
+                    ZtXm = ZtX * active[:, None]
+                    ZtZm = ZtZ * ibm.mask_outer(active)
+                    sse = (xx - 2.0 * jnp.sum(A * ZtXm)
+                           + jnp.sum(A * (ZtZm @ A)))
+                    gs_new, Zt0, ta0 = finish(gs, A, pi, active, sse, n_drop,
+                                              n_sat_g, Zt_p, ta_p)
                 return gs_new, Z_p, Zt0, ta0
 
             def block_vmap_data(X_full, gs, Z_c, Zt_c, ta_c):

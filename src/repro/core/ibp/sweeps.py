@@ -38,12 +38,18 @@ def uncollapsed_sweep(
     key: Array,
     backend: str = "jnp",
 ) -> Array:
-    """One full Gibbs sweep of Z | pi, A over active columns. Returns new Z."""
-    if backend == "pallas":
-        from repro.kernels.gibbs_flip import ops as _gf_ops
+    """One full Gibbs sweep of Z | pi, A over active columns. Returns new Z.
 
-        return _gf_ops.gibbs_flip(X, Z, A, pi, active, sigma_x, key)
-    return _uncollapsed_sweep_jnp(X, Z, A, pi, active, sigma_x, key)
+    Both backends run under the ``ibp_sweep`` named scope, so every op of
+    the sweep (the Pallas custom call included) carries it in its HLO
+    ``op_name`` (DESIGN.md §16).
+    """
+    with jax.named_scope("ibp_sweep"):
+        if backend == "pallas":
+            from repro.kernels.gibbs_flip import ops as _gf_ops
+
+            return _gf_ops.gibbs_flip(X, Z, A, pi, active, sigma_x, key)
+        return _uncollapsed_sweep_jnp(X, Z, A, pi, active, sigma_x, key)
 
 
 @partial(jax.jit, static_argnames=())

@@ -357,7 +357,15 @@ class MCMCDriver:
     def run(self, n_iters: int | None = None,
             on_eval: Callable[[dict], None] | None = None,
             crash_at: int | None = None):
-        """Main loop. ``crash_at`` raises mid-run (for restart tests)."""
+        """Main loop. ``crash_at`` raises mid-run (for restart tests).
+
+        Each cadence phase runs inside a ``jax.profiler.TraceAnnotation``
+        (``ibp:harvest``, ``ibp:poll``, ``ibp:canonical``, ``ibp:eval``,
+        ``ibp:ckpt``), on the profiler's clock, so a trace taken under
+        ``jax.profiler.trace`` puts each device-idle gap down to a phase
+        (DESIGN.md §16). With no profiler active a span costs about a
+        microsecond.
+        """
         spec = self.spec
         sampler = self.sampler
         n_iters = n_iters or spec.n_iters
@@ -392,6 +400,7 @@ class MCMCDriver:
                 self._bank = None
 
         t0 = time.time()
+        span = jax.profiler.TraceAnnotation
         for it in range(start, n_iters):
             if crash_at is not None and it == crash_at:
                 raise RuntimeError(f"injected crash at iteration {it}")
@@ -405,22 +414,26 @@ class MCMCDriver:
             if (self.bank_builder is not None
                     and (it + 1) > int(spec.harvest_burn * n_iters)
                     and (it + 1) % spec.harvest_every == 0):
-                self.bank_builder.add_state(gs, it=it + 1)
+                with span("ibp:harvest"):
+                    self.bank_builder.add_state(gs, it=it + 1)
             need_eval = (it + 1) % spec.eval_every == 0 or last
             need_ckpt = (it + 1) % spec.ckpt_every == 0 or last
             # pulling gs.overflow blocks the host on the iteration's whole
             # computation, so check at a bounded cadence, not every step —
             # detection delay is <= overflow_every iterations (DESIGN.md §10)
-            overflowed = (
-                need_eval or need_ckpt
-                or (it + 1) % spec.overflow_every == 0
-            ) and int(jnp.max(gs.overflow)) > 0
+            overflowed = False
+            if (need_eval or need_ckpt
+                    or (it + 1) % spec.overflow_every == 0):
+                with span("ibp:poll"):
+                    overflowed = int(jnp.max(gs.overflow)) > 0
             if need_eval or need_ckpt or overflowed:
                 # canonical layout is materialized at cadence only — the
                 # hot loop never leaves the layout's native state
-                ss = sampler.to_canonical(st)
+                with span("ibp:canonical"):
+                    ss = sampler.to_canonical(st)
             if need_eval:
-                rec = self.evaluate(gs, ss, it + 1, time.time() - t0)
+                with span("ibp:eval"):
+                    rec = self.evaluate(gs, ss, it + 1, time.time() - t0)
                 self.history.append(rec)
                 if on_eval:
                     on_eval(rec)
@@ -431,30 +444,35 @@ class MCMCDriver:
                 # checkpoint whose re-run re-harvests — prune_after on
                 # restore reconciles — whereas checkpoint-first would
                 # resume PAST unsaved harvests and lose them forever
-                if self.bank_builder is not None and len(self.bank_builder):
-                    self.save_bank()
-                save_pytree(spec.ckpt_dir, self._to_ckpt(gs, ss), it + 1)
-                # adaptive K_tail rides the checkpoint boundary (the one
-                # place tails are provably empty): saturation since the
-                # last boundary doubles the tail width in-process — the
-                # just-written checkpoint stays valid (tails are not
-                # serialized; a restart re-grows if saturation returns)
-                if spec.k_tail_grow > 0 and not last and not overflowed:
-                    gs, ss, grew = self._maybe_grow_tail(gs, ss)
-                    if grew:
-                        spec = self.spec
-                        sampler = self.sampler
-                        st = sampler.from_canonical(ss)
+                with span("ibp:ckpt"):
+                    if (self.bank_builder is not None
+                            and len(self.bank_builder)):
+                        self.save_bank()
+                    save_pytree(spec.ckpt_dir, self._to_ckpt(gs, ss), it + 1)
+                    # adaptive K_tail rides the checkpoint boundary (the
+                    # one place tails are provably empty): saturation
+                    # since the last boundary doubles the tail width
+                    # in-process — the just-written checkpoint stays
+                    # valid (tails are not serialized; a restart re-grows
+                    # if saturation returns)
+                    if spec.k_tail_grow > 0 and not last and not overflowed:
+                        gs, ss, grew = self._maybe_grow_tail(gs, ss)
+                        if grew:
+                            spec = self.spec
+                            sampler = self.sampler
+                            st = sampler.from_canonical(ss)
             if overflowed:
                 # capacity growth: checkpoint + restart with larger K_max.
                 # the bank is saved too (bank-first, as above) — the
                 # restart resumes AFTER this iteration, so harvests since
                 # the last cadence save would otherwise be dropped
                 if not need_ckpt:
-                    if (self.bank_builder is not None
-                            and len(self.bank_builder)):
-                        self.save_bank()
-                    save_pytree(spec.ckpt_dir, self._to_ckpt(gs, ss), it + 1)
+                    with span("ibp:ckpt"):
+                        if (self.bank_builder is not None
+                                and len(self.bank_builder)):
+                            self.save_bank()
+                        save_pytree(spec.ckpt_dir, self._to_ckpt(gs, ss),
+                                    it + 1)
                 raise RuntimeError(
                     f"K_max={spec.K_max} overflow at it={it}; restart with "
                     f"2x K_max"

@@ -109,6 +109,31 @@ def test_fused_sync_matches_staged():
     assert "OK fused == staged" in out
 
 
+def test_shardmap_step_carries_scopes():
+    """Both shard_map sync schedules compile with the step's three named
+    scopes (DESIGN.md §16); their all-reduces lie inside ``ibp_sync``."""
+    out = run_with_devices("""
+        import re, jax
+        from repro.data import cambridge_data
+        from repro.core.ibp import IBPHypers, SamplerSpec, build_sampler
+        X, _, _ = cambridge_data(N=64, seed=9)
+        for sync in ('staged', 'fused'):
+            spec = SamplerSpec(P=4, K_max=12, K_tail=4, K_init=3, L=2,
+                               data='shardmap', sync=sync)
+            s = build_sampler(spec, IBPHypers(), X)
+            gs, st = s.init(jax.random.key(3))
+            hlo = s._fns.step.lower(s._Xn, gs, *st).compile().as_text()
+            names = re.findall(r'op_name="([^"]*)"', hlo)
+            for scope in ('ibp_sweep', 'ibp_tail', 'ibp_sync'):
+                assert any(scope + '/' in n for n in names), (sync, scope)
+            ars = re.findall(
+                r'= [^\\n]*? all-reduce\\([^\\n]*?op_name="([^"]*)"', hlo)
+            assert ars and all('ibp_sync/' in n for n in ars), (sync, ars)
+            print('OK', sync, len(ars))
+    """, n_devices=4)
+    assert "OK staged" in out and "OK fused" in out
+
+
 def test_moe_a2a_matches_gather_dispatch():
     """The shard_map all-to-all MoE dispatch computes the same function as
     the global-capacity gather baseline when nothing drops (capacity_factor

@@ -85,3 +85,29 @@ def test_kernel_compiles_for_v5e(name, size, one_chip):
     fn, shapes = _kernel_case(name, *SIZES[size])
     compiled = _lower(fn, shapes, one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_sweep_carries_its_scope_for_v5e(one_chip, monkeypatch):
+    """The uncollapsed sweep's ``ibp_sweep`` scope (DESIGN.md §16) reaches
+    the compiled gibbs_flip custom call, not only the jnp sweep's ops."""
+    import re
+
+    from repro.core.ibp.sweeps import uncollapsed_sweep
+    from repro.kernels.gibbs_flip import ops as gf_ops
+
+    # compile the kernel, not its interpreter, for the described chip
+    monkeypatch.setattr(gf_ops, "default_interpret", lambda: False)
+    N, D, K = SIZES["paper"]
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+
+    def sweep(X, Z, A, pi, active, sigma_x, key):
+        return uncollapsed_sweep(X, Z, A, pi, active, sigma_x, key,
+                                 backend="pallas")
+
+    f32 = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+           for s in [(N, D), (N, K), (K, D), (K,), (K,), ()]]
+    hlo = jax.jit(sweep).lower(*f32, key).compile().as_text()
+    calls = re.findall(r"= [^\n]*? custom-call\([^\n]*?"
+                       r"custom_call_target=\"tpu_custom_call\"[^\n]*?"
+                       r"op_name=\"([^\"]*)\"", hlo)
+    assert calls and all("ibp_sweep/" in c for c in calls), calls
