@@ -112,10 +112,19 @@ def latest_step(path: str) -> int | None:
     return steps[-1] if steps else None
 
 
-def load_pytree(path: str, template: Any, step: int) -> Any:
+def load_pytree(path: str, template: Any, step: int,
+                older: tuple[Any, ...] = ()) -> Any:
+    """Load step ``step`` against ``template``. ``older`` are templates
+    of earlier state layouts: when the file's leaf count does not match
+    ``template``, the first of them whose count matches is used instead,
+    and the caller fills in what that layout lacks."""
     fname = os.path.join(path, f"step_{step:09d}.npz")
     with np.load(fname) as data:
         leaves = [data[f"leaf_{i:05d}"] for i in range(len(data.files))]
+    for t in (template, *older):
+        if len(jax.tree.leaves(t)) == len(leaves):
+            template = t
+            break
     _, treedef = jax.tree.flatten(template)
     t_leaves = jax.tree.leaves(template)
     if len(leaves) != len(t_leaves):
@@ -137,9 +146,11 @@ def load_pytree(path: str, template: Any, step: int) -> Any:
     return jax.tree.unflatten(treedef, cast)
 
 
-def restore(path: str, template: Any) -> tuple[Any, int] | None:
-    """Load the newest complete checkpoint, or None if none exists."""
+def restore(path: str, template: Any,
+            older: tuple[Any, ...] = ()) -> tuple[Any, int] | None:
+    """Load the newest complete checkpoint, or None if none exists
+    (``older`` as in ``load_pytree``)."""
     step = latest_step(path)
     if step is None:
         return None
-    return load_pytree(path, template, step), step
+    return load_pytree(path, template, step, older), step

@@ -94,8 +94,16 @@ def _log_poisson(j: Array, lam: Array) -> Array:
     return j * jnp.log(lam) - lam - jax.lax.lgamma(j + 1.0)
 
 
+def _mh_draws(kdish: Array, lam: Array, dtype) -> tuple[Array, Array]:
+    """The MH birth's random numbers for one row, from its dish key
+    alone: the proposal j ~ Poisson(lam) and the acceptance uniform."""
+    kprop, kacc = jax.random.split(kdish)
+    j_prop = jax.random.poisson(kprop, lam).astype(dtype)
+    return j_prop, jax.random.uniform(kacc, (), dtype=dtype)
+
+
 def _sample_dishes(kdish, q, mean, x_n, active_m, z, alpha, sx, sa, N, D,
-                   birth, n_free_extra=0.0):
+                   birth, n_free_extra=0.0, mh=None):
     """Shared new-dish move: returns (z', active', newbits, j_new, sat).
 
     ``birth`` selects the move:
@@ -117,6 +125,10 @@ def _sample_dishes(kdish, q, mean, x_n, active_m, z, alpha, sx, sa, N, D,
     count: the draw must see the CANONICAL free capacity (what the
     oracle sees), even when only the in-block slots are placeable — the
     caller detects non-placeable births via ``j_new`` vs ``newbits``.
+
+    ``mh`` is the row's ``_mh_draws``, when the caller drew them ahead
+    (the packed scan draws every row's at once); else they are drawn
+    here from ``kdish``.
     """
     inv2s2 = 0.5 / (sx**2)
     lam = alpha / N
@@ -137,12 +149,12 @@ def _sample_dishes(kdish, q, mean, x_n, active_m, z, alpha, sx, sa, N, D,
         sat = jnp.zeros((), jnp.bool_)
     else:
         # paper's MH: propose j ~ Poisson(lam), accept w.p. lik(j)/lik(0)
-        kprop, kacc = jax.random.split(kdish)
-        j_prop = jax.random.poisson(kprop, lam).astype(x_n.dtype)
+        j_prop, u_acc = mh if mh is not None else _mh_draws(kdish, lam,
+                                                            x_n.dtype)
         ok = (j_prop <= jnp.minimum(float(J_MAX), n_free))
         j_idx = jnp.clip(j_prop, 0, J_MAX).astype(jnp.int32)
         dll = ll_j[j_idx] - ll_j[0]
-        acc = jnp.log(jax.random.uniform(kacc, (), dtype=x_n.dtype)) < dll
+        acc = jnp.log(u_acc) < dll
         j_new = jnp.where(ok & acc, j_prop, 0.0)
         # capacity-bound rejection of an otherwise-accepted proposal: the
         # truncation (not the likelihood) vetoed these births
@@ -362,6 +374,12 @@ def _packed_scan(
     # the carry's ubuf is an empty placeholder (cond-free hot loop)
     u_all = None if chunked else gen_u(jnp.zeros((), jnp.int32))
 
+    # the MH birth's draws depend on the row's dish key alone (lam =
+    # alpha/N is fixed for the scan): draw every row's at once, as
+    # vectors, instead of one scalar Poisson loop pair per row step
+    mh_all = (jax.vmap(lambda kd: _mh_draws(kd, alpha / N, X.dtype))(
+        kdish_all) if birth == "mh" else None)
+
     def body(c: _PackedCarry) -> _PackedCarry:
         n = c.n
         active, ZtZ, ZtX, m = c.active, c.ZtZ, c.ZtX, c.m
@@ -477,6 +495,7 @@ def _packed_scan(
         z2, active_new, newbits, j_new, sat = _sample_dishes(
             kdish, q, mean, x_n, active_m, z, alpha, sx, sa, N, D, birth,
             n_free_extra=n_out_free,
+            mh=None if mh_all is None else (mh_all[0][j], mh_all[1][j]),
         )
         top_col = jnp.max(jnp.where(newbits > 0.5, cols, -1))
         birth_ovf = (jnp.sum(newbits) < j_new) | (top_col >= min_out)

@@ -89,6 +89,8 @@ class HybridGlobal:
     tail_sat: Array  # () int32 — tail rows whose accepted MH birth was
     #                  vetoed by K_tail capacity (drives adaptive K_tail
     #                  growth at the driver's restart boundary)
+    tail_refresh: Array  # () int32 — tail rows that took the exact
+    #                      refactorization (cadence + drift monitor)
 
 
 @jax.tree_util.register_dataclass
@@ -149,6 +151,7 @@ def init_hybrid(
         it=jnp.asarray(0, jnp.int32),
         overflow=jnp.asarray(0, jnp.int32),
         tail_sat=jnp.asarray(0, jnp.int32),
+        tail_refresh=jnp.asarray(0, jnp.int32),
     )
     ss = HybridShard(
         Z=Z,
@@ -174,7 +177,7 @@ def _tail_sub_iteration(
     collapsed_backend: str = "ref",
     chol_refresh: int = DEFAULT_REFRESH,
     k_live_pack: bool = False,
-) -> tuple[Array, Array, Array]:
+) -> tuple[Array, Array, Array, Array]:
     """Collapsed Gibbs + MH births on the tail (runs on p' only).
 
     ``collapsed_backend`` selects the row-step implementation (DESIGN.md
@@ -187,9 +190,10 @@ def _tail_sub_iteration(
     the tail gains from ``pack=True`` is the carried G = HHᵀ (DESIGN.md
     §12).
 
-    Returns (Z_tail, tail_active, n_sat): ``n_sat`` counts rows whose
-    accepted MH birth was vetoed purely by K_tail capacity — the tail-
-    saturation signal driving adaptive K_tail growth.
+    Returns (Z_tail, tail_active, n_sat, n_refresh): ``n_sat`` counts
+    rows whose accepted MH birth was vetoed purely by K_tail capacity —
+    the tail-saturation signal driving adaptive K_tail growth;
+    ``n_refresh`` counts rows that took the exact refactorization.
     """
     with jax.named_scope("ibp_tail"):
         # residual given instantiated features = the tail model's data
@@ -197,9 +201,9 @@ def _tail_sub_iteration(
         m_t = jnp.sum(Z_tail, axis=0)
         ZtZ_t = Z_tail.T @ Z_tail
         ZtR = Z_tail.T @ R
-        # u_chunk_rows=n_rows: this entry is vmapped (chains/shards) — the
-        # chunked refill would lower to select and regenerate per row
-        Z_tail, tail_active, _, _, m_t, _, n_sat = collapsed_row_scan(
+        # u_chunk_rows=n_rows: under chains="vmap" this entry is batched —
+        # the chunked refill would lower to select and regenerate per row
+        Z_tail, tail_active, _, _, m_t, n_refresh, n_sat = collapsed_row_scan(
             Z_tail, tail_active, ZtZ_t, ZtR, m_t, R, key,
             gs.alpha, gs.sigma_x, gs.sigma_a,
             N=N_global, birth="mh", backend=collapsed_backend,
@@ -209,7 +213,14 @@ def _tail_sub_iteration(
         # prune dead tail columns
         tail_active = tail_active * (m_t > 0.5)
         Z_tail = Z_tail * tail_active[None, :]
-    return Z_tail, tail_active, n_sat
+    return Z_tail, tail_active, n_sat, n_refresh
+
+
+def _sub_keys(key_shard: Array, l: Array) -> Array:
+    """Keys of sub-iteration ``l`` on the shard whose key is ``key_shard``
+    = ``fold_in(gs.key, shard)``: (2,) keys, the sweep's then the tail's.
+    Every layout derives them here, so all draw the same numbers."""
+    return jax.random.split(jax.random.fold_in(key_shard, l))
 
 
 def shard_sub_iterations(
@@ -225,43 +236,97 @@ def shard_sub_iterations(
     collapsed_backend: str = "ref",
     chol_refresh: int = DEFAULT_REFRESH,
     k_live_pack: bool = False,
-) -> tuple[Array, Array, Array, Array]:
-    """L sub-iterations of the paper's inner loop on one shard.
+) -> tuple[Array, Array, Array, Array, Array]:
+    """L sub-iterations of the paper's inner loop on one shard (the
+    shard_map layouts: the shard is this device, so the ``cond`` on p'
+    is a real branch).
 
-    Returns (Z, Z_tail, tail_active, n_sat) — ``n_sat`` is the tail-
-    saturation count summed over this shard's tail sub-iterations
-    (nonzero only on p').
+    Returns (Z, Z_tail, tail_active, n_sat, n_refresh) — the tail's
+    counts summed over this shard's sub-iterations (nonzero only on p').
     """
     key_shard = jax.random.fold_in(gs.key, shard_idx)
     is_pprime = shard_idx == gs.p_prime
 
     def one(l, carry):
-        Z, Z_tail, tail_active, n_sat = carry
-        kl = jax.random.fold_in(key_shard, l)
-        ku, kt = jax.random.split(kl)
+        Z, Z_tail, tail_active, n_sat, n_ref = carry
+        ku, kt = _sub_keys(key_shard, l)
         Z = uncollapsed_sweep(
             X_p, Z, gs.A, gs.pi, gs.active, gs.sigma_x, ku, backend=backend
         )
 
         def with_tail(args):
-            Z_tail, tail_active, n_sat = args
-            Z_tail, tail_active, sat = _tail_sub_iteration(
+            Z_tail, tail_active, n_sat, n_ref = args
+            Z_tail, tail_active, sat, ref = _tail_sub_iteration(
                 X_p, Z, Z_tail, tail_active, gs, N_global, kt,
                 collapsed_backend=collapsed_backend,
                 chol_refresh=chol_refresh,
                 k_live_pack=k_live_pack,
             )
-            return Z_tail, tail_active, n_sat + sat
+            return Z_tail, tail_active, n_sat + sat, n_ref + ref
 
-        Z_tail, tail_active, n_sat = jax.lax.cond(
-            is_pprime, with_tail, lambda a: a, (Z_tail, tail_active, n_sat)
+        Z_tail, tail_active, n_sat, n_ref = jax.lax.cond(
+            is_pprime, with_tail, lambda a: a,
+            (Z_tail, tail_active, n_sat, n_ref),
         )
-        return Z, Z_tail, tail_active, n_sat
+        return Z, Z_tail, tail_active, n_sat, n_ref
 
-    Z, Z_tail, tail_active, n_sat = jax.lax.fori_loop(
-        0, L, one, (Z, Z_tail, tail_active, jnp.zeros((), jnp.int32))
+    zero = jnp.zeros((), jnp.int32)
+    return jax.lax.fori_loop(
+        0, L, one, (Z, Z_tail, tail_active, zero, zero)
     )
-    return Z, Z_tail, tail_active, n_sat
+
+
+def _vmap_sub_iterations(
+    X_shards: Array,
+    Z: Array,
+    Z_tail: Array,
+    tail_active: Array,
+    gs: HybridGlobal,
+    N_global: float,
+    L: int,
+    backend: str,
+    collapsed_backend: str,
+    chol_refresh: int,
+    k_live_pack: bool,
+) -> tuple[Array, Array, Array, Array, Array]:
+    """L sub-iterations of the paper's inner loop over the P simulated
+    shards (the data-vmap layouts; leading shard axis on X_shards, Z,
+    Z_tail, tail_active).
+
+    Sub-iteration l sweeps every shard, vmapped, then runs the tail once,
+    unbatched, on p''s slice and writes it back at p'; the other shards'
+    tail state is left as it is. Batched over shards, the tail's
+    ``lax.cond``s would lower to selects that run both branches on every
+    row (the exact refactorization included) for all P shards (DESIGN.md
+    §12). Returns (Z, Z_tail, tail_active, n_sat, n_refresh).
+    """
+    pp = gs.p_prime
+    key_shards = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+        gs.key, jnp.arange(X_shards.shape[0]))
+    X_pp = X_shards[pp]
+    sweep = jax.vmap(partial(uncollapsed_sweep, backend=backend),
+                     in_axes=(0, 0, None, None, None, None, 0))
+
+    def one(l, carry):
+        Z, Z_tail, tail_active, n_sat, n_ref = carry
+        keys = jax.vmap(_sub_keys, in_axes=(0, None))(key_shards, l)
+        # the scope outside the vmap keeps ``ibp_sweep`` a component of
+        # its own in the op_name (inside, it reads ``vmap(ibp_sweep)``)
+        with jax.named_scope("ibp_sweep"):
+            Z = sweep(X_shards, Z, gs.A, gs.pi, gs.active, gs.sigma_x,
+                      keys[:, 0])
+        Zt, ta, sat, ref = _tail_sub_iteration(
+            X_pp, Z[pp], Z_tail[pp], tail_active[pp], gs, N_global,
+            keys[pp, 1], collapsed_backend=collapsed_backend,
+            chol_refresh=chol_refresh, k_live_pack=k_live_pack,
+        )
+        return (Z, Z_tail.at[pp].set(Zt), tail_active.at[pp].set(ta),
+                n_sat + sat, n_ref + ref)
+
+    zero = jnp.zeros((), jnp.int32)
+    return jax.lax.fori_loop(
+        0, L, one, (Z, Z_tail, tail_active, zero, zero)
+    )
 
 
 def promote_tail(
@@ -389,20 +454,29 @@ def _hybrid_iteration_body(
     and the chains-mesh x data-vmap layout runs it per chain device
     (``build_hybrid_fns``).
     """
-    P_, N_p, D = X_shards.shape
-
-    sub = partial(
-        shard_sub_iterations, N_global=N_g, L=L, backend=backend,
-        collapsed_backend=collapsed_backend, chol_refresh=chol_refresh,
-        k_live_pack=k_live_pack,
+    Z, Z_tail, tail_active, n_sat, n_ref = _vmap_sub_iterations(
+        X_shards, ss.Z, ss.Z_tail, ss.tail_active, gs, N_g, L, backend,
+        collapsed_backend, chol_refresh, k_live_pack,
     )
-    Z, Z_tail, tail_active, n_sat = jax.vmap(
-        sub, in_axes=(0, 0, 0, 0, None, 0)
-    )(X_shards, ss.Z, ss.Z_tail, ss.tail_active, gs, jnp.arange(P_))
-    with jax.named_scope("ibp_sync"):
-        n_sat = jnp.sum(n_sat)  # only p' contributes
+    return _vmap_sync(X_shards, gs, Z, Z_tail, tail_active, n_sat, n_ref,
+                      hyp, N_g)
 
-        # ---- master sync (simulated psum = sum over shard axis)
+
+def _vmap_sync(
+    X_shards: Array,
+    gs: HybridGlobal,
+    Z: Array,
+    Z_tail: Array,
+    tail_active: Array,
+    n_sat: Array,
+    n_ref: Array,
+    hyp,
+    N_g: float,
+) -> tuple[HybridGlobal, HybridShard]:
+    """The master sync of the data-vmap layouts (simulated psum = sum
+    over the shard axis), after the sub-iterations; clears the tails."""
+    P_, N_p, D = X_shards.shape
+    with jax.named_scope("ibp_sync"):
         tail_g = jnp.sum(tail_active, axis=0)  # only p' is nonzero
         Z, active_new, n_drop = jax.vmap(
             promote_tail, in_axes=(0, 0, None, None)
@@ -429,11 +503,12 @@ def _hybrid_iteration_body(
             p_prime=p_prime, it=gs.it + 1,
             overflow=gs.overflow + n_drop,
             tail_sat=gs.tail_sat + n_sat,
+            tail_refresh=gs.tail_refresh + n_ref,
         )
         ss_new = HybridShard(
             Z=Z,
-            Z_tail=jnp.zeros_like(ss.Z_tail),
-            tail_active=jnp.zeros_like(ss.tail_active),
+            Z_tail=jnp.zeros_like(Z_tail),
+            tail_active=jnp.zeros_like(tail_active),
         )
     return gs_new, ss_new
 
@@ -483,17 +558,14 @@ def _hybrid_stale_body(
     make the next iteration's sub-iterations replay the exact same
     per-(shard, l) uniform stream.
     """
-    P_ = X_shards.shape[0]
     gs_sweep = dataclasses.replace(gs, key=jax.random.fold_in(gs.key, 13))
-    sub = partial(shard_sub_iterations, N_global=N_g, L=L, backend=backend,
-                  collapsed_backend=collapsed_backend,
-                  chol_refresh=chol_refresh, k_live_pack=k_live_pack)
-    Z, Z_tail, tail_active, _ = jax.vmap(
-        sub, in_axes=(0, 0, 0, 0, None, 0)
-    )(X_shards, ss.Z, ss.Z_tail, ss.tail_active, gs_sweep, jnp.arange(P_))
-    # stale passes don't touch gs — saturation on them is uncounted (the
-    # pass is explicitly non-exact; the counter stays a sync-boundary
-    # quantity)
+    Z, Z_tail, tail_active, _, _ = _vmap_sub_iterations(
+        X_shards, ss.Z, ss.Z_tail, ss.tail_active, gs_sweep, N_g, L,
+        backend, collapsed_backend, chol_refresh, k_live_pack,
+    )
+    # stale passes don't touch gs — saturation and refreshes on them are
+    # uncounted (the pass is explicitly non-exact; the counters stay
+    # sync-boundary quantities)
     gs_out = dataclasses.replace(gs, key=jax.random.fold_in(gs.key, 14))
     return gs_out, HybridShard(Z=Z, Z_tail=Z_tail, tail_active=tail_active)
 
@@ -633,7 +705,8 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
         def call(X, gs: HybridGlobal, Z, Z_tail, tail_active):
             D = X.shape[-1]
 
-            def finish(gs, A, pi, active, sse, n_drop, n_sat, Zt_p, ta_p):
+            def finish(gs, A, pi, active, sse, n_drop, n_sat, n_ref, Zt_p,
+                       ta_p):
                 sigma_x, sigma_a, alpha, p_prime = master_step2(
                     sse, A, active, gs, hyp, N_g, D, P_
                 )
@@ -644,6 +717,7 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                     p_prime=p_prime, it=gs.it + 1,
                     overflow=gs.overflow + n_drop,
                     tail_sat=gs.tail_sat + n_sat,
+                    tail_refresh=gs.tail_refresh + n_ref,
                 )
                 return gs_new, jnp.zeros_like(Zt_p), jnp.zeros_like(ta_p)
 
@@ -653,7 +727,7 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                 gs_sweep = dataclasses.replace(
                     gs, key=jax.random.fold_in(gs.key, 13)
                 )
-                Z_p, Zt_p, ta, _ = shard_sub_iterations(
+                Z_p, Zt_p, ta, _, _ = shard_sub_iterations(
                     X_p, Z_p, Zt_p, ta, gs_sweep, idx, N_g, L, be, cb, cr,
                     pk,
                 )
@@ -665,12 +739,12 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
             def block_staged(X_p, gs, Z_p, Zt_p, ta_p):
                 ta = ta_p[0]  # (1, K_tail) local block -> (K_tail,)
                 idx = jax.lax.axis_index(data_axes)
-                Z_p, Zt_p2, ta, n_sat = shard_sub_iterations(
+                Z_p, Zt_p2, ta, n_sat, n_ref = shard_sub_iterations(
                     X_p, Z_p, Zt_p, ta, gs, idx, N_g, L, be, cb, cr, pk
                 )
                 with jax.named_scope("ibp_sync"):
-                    tail_g, n_sat_g = jax.lax.psum((ta, n_sat),  # AR 1
-                                                   data_axes)
+                    tail_g, n_sat_g, n_ref_g = jax.lax.psum(      # AR 1
+                        (ta, n_sat, n_ref), data_axes)
                     Z_p, active_new, n_drop = promote_tail(Z_p, Zt_p2, tail_g,
                                                            gs.active)
                     stats = local_stats(X_p, Z_p)
@@ -681,13 +755,13 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                     sse = jax.lax.psum(                                  # AR 3
                         local_sse(X_p, Z_p, A, active), data_axes)
                     gs_new, Zt0, ta0 = finish(gs, A, pi, active, sse, n_drop,
-                                              n_sat_g, Zt_p, ta_p)
+                                              n_sat_g, n_ref_g, Zt_p, ta_p)
                 return gs_new, Z_p, Zt0, ta0
 
             def block_fused(X_p, gs, Z_p, Zt_p, ta_p):
                 ta = ta_p[0]
                 idx = jax.lax.axis_index(data_axes)
-                Z_p, Zt_p2, ta, n_sat = shard_sub_iterations(
+                Z_p, Zt_p2, ta, n_sat, n_ref = shard_sub_iterations(
                     X_p, Z_p, Zt_p, ta, gs, idx, N_g, L, be, cb, cr, pk
                 )
                 with jax.named_scope("ibp_sync"):
@@ -698,8 +772,9 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                     # every shard re-derives after the reduce)
                     Z_stats, _, _ = promote_tail(Z_p, Zt_p2, ta, gs.active)
                     stats = local_stats(X_p, Z_stats)
-                    # the saturation count rides the single payload as a
-                    # float scalar (small exact integers — f32-exact)
+                    # the saturation and refresh counts ride the single
+                    # payload as float scalars (small exact integers —
+                    # f32-exact)
                     payload = jnp.concatenate([
                         stats["ZtZ"].reshape(-1),
                         stats["ZtX"].reshape(-1),
@@ -707,6 +782,7 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                         ta,
                         jnp.sum(X_p * X_p)[None],
                         n_sat.astype(X_p.dtype)[None],
+                        n_ref.astype(X_p.dtype)[None],
                     ])
                     g = jax.lax.psum(payload, data_axes)    # AR (only)
                     o1 = K_max * K_max
@@ -715,8 +791,9 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                     ZtX = g[o1:o2].reshape(K_max, X_p.shape[1])
                     m_g = g[o2:o2 + K_max]
                     tail_g = g[o2 + K_max:o2 + K_max + K_tail]
-                    xx = g[-2]
-                    n_sat_g = g[-1].astype(jnp.int32)
+                    xx = g[-3]
+                    n_sat_g = g[-2].astype(jnp.int32)
+                    n_ref_g = g[-1].astype(jnp.int32)
                     Z_p, active_new, n_drop = promote_tail(Z_p, Zt_p2, tail_g,
                                                            gs.active)
                     A, pi, active, m = master_step1(
@@ -730,7 +807,7 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                     sse = (xx - 2.0 * jnp.sum(A * ZtXm)
                            + jnp.sum(A * (ZtZm @ A)))
                     gs_new, Zt0, ta0 = finish(gs, A, pi, active, sse, n_drop,
-                                              n_sat_g, Zt_p, ta_p)
+                                              n_sat_g, n_ref_g, Zt_p, ta_p)
                 return gs_new, Z_p, Zt0, ta0
 
             def block_vmap_data(X_full, gs, Z_c, Zt_c, ta_c):

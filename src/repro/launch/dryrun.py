@@ -292,6 +292,8 @@ def run_ibp_cell(mesh_name: str, *, N: int = 1 << 20, D: int = 36,
                 it=jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
                 overflow=jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
                 tail_sat=jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
+                tail_refresh=jax.ShapeDtypeStruct((), jnp.int32,
+                                                  sharding=rep),
             )
             args = (rs((N, D)), gs, rs((N, K_max)), rs((N, K_tail)),
                     jax.ShapeDtypeStruct((P_, K_tail), f32, sharding=row_sh))

@@ -236,6 +236,9 @@ class MCMCDriver:
     def _from_ckpt(self, blob: dict) -> tuple[HybridGlobal, HybridShard]:
         spec = self.spec
         gs: HybridGlobal = blob["gs"]
+        if gs.tail_refresh is None:  # written before the counter existed
+            gs = dataclasses.replace(
+                gs, tail_refresh=jnp.zeros_like(gs.tail_sat))
         Zg = blob["Z_global"]
         K_ck = Zg.shape[-1]
         if K_ck > spec.K_max:
@@ -369,7 +372,11 @@ class MCMCDriver:
         spec = self.spec
         sampler = self.sampler
         n_iters = n_iters or spec.n_iters
-        restored = restore(spec.ckpt_dir, self._template())
+        tpl = self._template()
+        # checkpoints written before gs carried tail_refresh restore it at 0
+        legacy = dict(tpl, gs=dataclasses.replace(tpl["gs"],
+                                                  tail_refresh=None))
+        restored = restore(spec.ckpt_dir, tpl, older=(legacy,))
         if restored is not None:
             blob, start = restored[0], int(restored[1])
             gs, ss = self._from_ckpt(blob)
@@ -536,6 +543,9 @@ class MCMCDriver:
                 "tail_sat": int(jnp.max(gs.tail_sat)),
                 "tail_sat_chains": [int(s)
                                     for s in np.asarray(gs.tail_sat)],
+                "tail_refresh": int(jnp.max(gs.tail_refresh)),
+                "tail_refresh_chains": [
+                    int(r) for r in np.asarray(gs.tail_refresh)],
             }
             if self.X_eval is not None:
                 ev = jax.vmap(
@@ -558,6 +568,7 @@ class MCMCDriver:
                 )),
                 "K_tail": int(self.spec.K_tail),
                 "tail_sat": int(gs.tail_sat),
+                "tail_refresh": int(gs.tail_refresh),
             }
             if self.X_eval is not None:
                 rec["joint_ll_eval"] = float(heldout_joint_loglik(

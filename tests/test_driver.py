@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.checkpoint import latest_step
+from repro.checkpoint import latest_step, restore, save_pytree
 from repro.core.ibp import IBPHypers
 from repro.data import cambridge_data
 from repro.runtime import DriverConfig, MCMCDriver
@@ -246,3 +246,48 @@ def test_k_tail_fixed_when_grow_disabled(data, tmp_path):
     assert ss.Z_tail.shape[-1] == 2
     assert drv.history[-1]["K_tail"] == 2
     assert "tail_sat" in drv.history[-1]
+
+
+def _refresh_cfg(ckpt_dir, n_iters):
+    # N_p = 16 rows per shard, a refresh every R = 4 rows
+    return DriverConfig(P=3, K_max=12, K_tail=4, K_init=2, L=2,
+                        n_iters=n_iters, ckpt_every=2, eval_every=1,
+                        collapsed_backend="fast", chol_refresh=4,
+                        ckpt_dir=str(ckpt_dir))
+
+
+def test_tail_refresh_counts_the_refresh_cadence(data, tmp_path):
+    """gs.tail_refresh counts the tail rows that took the exact
+    refactorization: at least L x floor(N_p / R) an iteration from the
+    cadence alone, and every eval record carries the running count."""
+    cfg = _refresh_cfg(tmp_path, 4)
+    drv = MCMCDriver(data, cfg, IBPHypers())
+    gs, _ = drv.run()
+    per_iter = cfg.L * ((data.shape[0] // cfg.P) // cfg.chol_refresh)
+    assert int(gs.tail_refresh) >= cfg.n_iters * per_iter
+    counts = [rec["tail_refresh"] for rec in drv.history]
+    assert counts[-1] == int(gs.tail_refresh)
+    assert all(b - a >= per_iter for a, b in zip([0] + counts, counts))
+
+
+def test_checkpoint_without_tail_refresh_restores_it_at_zero(data,
+                                                              tmp_path):
+    """A checkpoint written before gs carried tail_refresh resumes with
+    the count at 0; the rest of the state resumes as it was."""
+    MCMCDriver(data, _refresh_cfg(tmp_path / "new", 2), IBPHypers()).run()
+    drv = MCMCDriver(data, _refresh_cfg(tmp_path / "new", 3), IBPHypers())
+    tpl = drv._template()
+    blob, step = restore(str(tmp_path / "new"), tpl)
+    assert int(blob["gs"].tail_refresh) > 0
+    old = dict(blob, gs=dataclasses.replace(blob["gs"], tail_refresh=None))
+    save_pytree(str(tmp_path / "old"), old, step)
+
+    gs_new, ss_new = drv.run()
+    gs_old, ss_old = MCMCDriver(data, _refresh_cfg(tmp_path / "old", 3),
+                                IBPHypers()).run()
+    np.testing.assert_array_equal(np.asarray(ss_old.Z),
+                                  np.asarray(ss_new.Z))
+    assert int(gs_old.it) == int(gs_new.it) == 3
+    # the old checkpoint's count restarts at 0: only iteration 3's remain
+    assert int(gs_old.tail_refresh) == (int(gs_new.tail_refresh)
+                                        - int(blob["gs"].tail_refresh))

@@ -65,8 +65,43 @@ def test_paper_step_loops_sit_under_their_scopes(paper_step_hlo):
     # the master's Beta / Gamma rejection loops
     gammas = [p for p in whiles if "jit(_gamma)" in p]
     assert gammas and all(scope_of(p) == "ibp_sync" for p in gammas)
-    # the L sub-iteration loop lies above the scopes and carries none
-    assert "jit(step_one)/vmap()/while" in whiles
+    # the L sub-iteration loop lies above the scopes and the shard vmap,
+    # and carries no scope
+    assert "jit(step_one)/while" in whiles
+
+
+def computations(hlo: str) -> dict[str, list[str]]:
+    """The instruction lines of each computation in HLO text, by name."""
+    comps: dict[str, list[str]] = {}
+    lines = None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            lines = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            lines = None
+        elif lines is not None:
+            lines.append(line)
+    return comps
+
+
+def test_paper_tail_row_step_branches(paper_step_hlo):
+    """The tail runs unbatched on p', so the row step's ``lax.cond``s stay
+    branches: the row scan's body holds ``conditional``s, and the exact
+    refactorization's Cholesky sits inside one of them, not at the top
+    level of every row (as under a shard-batched tail, where each cond
+    is a select that runs both branches)."""
+    # the row scan is the packed scan's one loop of unknown trip count
+    # (it stops early on a birth that does not fit the block)
+    bodies = [re.search(r"body=%([\w.-]+)", line).group(1)
+              for line in paper_step_hlo.splitlines()
+              if " while(" in line
+              and 'jit(_packed_scan)/while"' in line
+              and "known_trip_count" not in line]
+    assert len(bodies) == 1, bodies
+    top = computations(paper_step_hlo)[bodies[0]]
+    assert any(" conditional(" in line for line in top)
+    assert not any("cholesky" in line.lower() for line in top)
 
 
 def test_driver_run_leaves_cadence_spans(tmp_path):
