@@ -55,7 +55,7 @@ def posterior_features_hybrid(X, P, iters, L, K_max, seed):
         gs, ss = smp.step(gs, ss)
     Z = ss.Z.reshape(N, -1)
     ZtZ = (Z.T @ Z) * ibm.mask_outer(gs.active)
-    ZtX = (Z.T @ smp.Xs.reshape(N, -1)) * gs.active[:, None]
+    ZtX = (Z.T @ smp.X) * gs.active[:, None]
     A, _ = ibm.a_posterior(ZtZ, ZtX, gs.active, gs.sigma_x, gs.sigma_a)
     order = jnp.argsort(-jnp.sum(Z, axis=0) * gs.active)
     return np.asarray(A[order]), int(jnp.sum(gs.active))

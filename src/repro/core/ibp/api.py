@@ -236,8 +236,6 @@ class Sampler:
             )
         self.X_global = X[:N]
         self.N, self.D = N, X.shape[1]
-        self.Xs = jnp.asarray(self.X_global.reshape(spec.P, N // spec.P,
-                                                    self.D))
         self.chain_axis = spec.chain_axis
         self.mesh = self._make_mesh()
         self._flat = self.mesh is not None  # mesh-native (Z, Zt, ta) state
@@ -269,7 +267,8 @@ class Sampler:
                              axis_types=(AxisType.Auto,) * len(names))
 
     def _shardings(self):
-        """(data-rows, chains, chains x data-rows) NamedShardings."""
+        """(data-rows, chains, chains x data-rows, replicated)
+        NamedShardings."""
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as PS
 
@@ -279,20 +278,27 @@ class Sampler:
         c = NamedSharding(m, PS("chains")) if "chains" in names else None
         cd = (NamedSharding(m, PS("chains", "data"))
               if "chains" in names and "data" in names else None)
-        return d, c, cd
+        return d, c, cd, NamedSharding(m, PS())
 
     def _place_data(self):
+        """X where the step reads it, put there straight from host
+        memory: under data="shardmap" each device receives its own rows
+        and no device holds the whole matrix."""
+        Xs = self.X_global.reshape(self.spec.P, -1, self.D)
         if not self._flat:
-            return self.Xs
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as PS
-
+            return jnp.asarray(Xs)
+        d, _, _, rep = self._shardings()
         if self.spec.data == "shardmap":
             # (N, D) rows over the data axis, replicated over chains
-            return jax.device_put(jnp.asarray(self.X_global),
-                                  NamedSharding(self.mesh, PS("data")))
+            return jax.device_put(self.X_global, d)
         # chains="mesh" x data="vmap": full (P, N_p, D) copy per chain
-        return jax.device_put(self.Xs, NamedSharding(self.mesh, PS()))
+        return jax.device_put(Xs, rep)
+
+    @property
+    def X(self) -> jax.Array:
+        """The (N, D) training rows, on the devices the step reads them
+        from."""
+        return self._Xn.reshape(self.N, self.D)
 
     # ---- protocol ---------------------------------------------------------
     def init(self, key: jax.Array | None = None):
@@ -303,12 +309,12 @@ class Sampler:
             key = jax.random.key(spec.seed)
         kw = dict(K_tail=spec.K_tail, alpha=spec.alpha, sigma_x=spec.sigma_x,
                   sigma_a=spec.sigma_a, K_init=spec.K_init)
+        Xs = self._Xn.reshape(spec.P, self.N // spec.P, self.D)
         if self.chain_axis:
-            gs, ss = init_multichain(key, self.Xs, spec.n_chains, spec.K_max,
-                                     **kw)
+            gs, ss = init_multichain(key, Xs, spec.n_chains, spec.K_max, **kw)
         else:
-            gs, ss = init_hybrid(key, self.Xs, spec.K_max, **kw)
-        return gs, self.from_canonical(ss)
+            gs, ss = init_hybrid(key, Xs, spec.K_max, **kw)
+        return self.place_global(gs), self.from_canonical(ss)
 
     def step(self, gs, st):
         """One full hybrid iteration (sub-iterations + master sync)."""
@@ -340,11 +346,21 @@ class Sampler:
             tail_active=ta,
         )
 
+    def place_global(self, gs):
+        """The master's state where the step leaves it: on a mesh,
+        replicated (sharded over chains under chains="mesh"), so that a
+        state from ``init``, from a checkpoint or from ``step`` runs one
+        compiled step; as it is off a mesh."""
+        if not self._flat:
+            return gs
+        _, c, _, rep = self._shardings()
+        return jax.device_put(gs, c if self.chain_axis else rep)
+
     def from_canonical(self, ss: HybridShard):
         """Canonical HybridShard -> native device-resident state."""
         if not self._flat:
             return ss
-        d, c, cd = self._shardings()
+        d, c, cd, _ = self._shardings()
         spec = self.spec
         if spec.data == "vmap":       # chains-mesh, simulated data shards
             return (jax.device_put(ss.Z, c),

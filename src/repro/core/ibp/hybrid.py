@@ -743,17 +743,21 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                     X_p, Z_p, Zt_p, ta, gs, idx, N_g, L, be, cb, cr, pk
                 )
                 with jax.named_scope("ibp_sync"):
-                    tail_g, n_sat_g, n_ref_g = jax.lax.psum(      # AR 1
-                        (ta, n_sat, n_ref), data_axes)
+                    # AR 1: the other shards wait here for p′'s tail
+                    with jax.named_scope("ar_tail"):
+                        tail_g, n_sat_g, n_ref_g = jax.lax.psum(
+                            (ta, n_sat, n_ref), data_axes)
                     Z_p, active_new, n_drop = promote_tail(Z_p, Zt_p2, tail_g,
                                                            gs.active)
                     stats = local_stats(X_p, Z_p)
-                    stats = jax.lax.psum(stats, data_axes)              # AR 2
+                    with jax.named_scope("ar_stats"):
+                        stats = jax.lax.psum(stats, data_axes)
                     A, pi, active, m = master_step1(stats, active_new, gs,
                                                     N_g, D)
                     Z_p = Z_p * active[None, :]
-                    sse = jax.lax.psum(                                  # AR 3
-                        local_sse(X_p, Z_p, A, active), data_axes)
+                    sse_p = local_sse(X_p, Z_p, A, active)
+                    with jax.named_scope("ar_sse"):
+                        sse = jax.lax.psum(sse_p, data_axes)
                     gs_new, Zt0, ta0 = finish(gs, A, pi, active, sse, n_drop,
                                               n_sat_g, n_ref_g, Zt_p, ta_p)
                 return gs_new, Z_p, Zt0, ta0
@@ -784,7 +788,8 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                         n_sat.astype(X_p.dtype)[None],
                         n_ref.astype(X_p.dtype)[None],
                     ])
-                    g = jax.lax.psum(payload, data_axes)    # AR (only)
+                    with jax.named_scope("ar_payload"):
+                        g = jax.lax.psum(payload, data_axes)
                     o1 = K_max * K_max
                     o2 = o1 + K_max * X_p.shape[1]
                     ZtZ = g[:o1].reshape(K_max, K_max)

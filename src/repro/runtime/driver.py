@@ -380,6 +380,7 @@ class MCMCDriver:
         if restored is not None:
             blob, start = restored[0], int(restored[1])
             gs, ss = self._from_ckpt(blob)
+            gs = sampler.place_global(gs)
             st = sampler.from_canonical(ss)  # native, device-resident
             # a restart continues the harvest from the persisted bank
             # instead of overwriting it with a shorter ensemble...
@@ -516,7 +517,7 @@ class MCMCDriver:
 
     def evaluate(self, gs: HybridGlobal, ss: HybridShard, it: int,
                  elapsed: float) -> dict[str, Any]:
-        X = jnp.asarray(self.X_global)
+        X = self.sampler.X
         # the held-out scorer runs the gaussian_sse kernel outside any
         # shard_map, and a compiled Pallas kernel cannot be partitioned
         # over a mesh: it scores one device's copy of the parameters

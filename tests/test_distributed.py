@@ -111,12 +111,16 @@ def test_fused_sync_matches_staged():
 
 def test_shardmap_step_carries_scopes():
     """Both shard_map sync schedules compile with the step's three named
-    scopes (DESIGN.md §16); their all-reduces lie inside ``ibp_sync``."""
+    scopes (DESIGN.md §16); each all-reduce lies inside ``ibp_sync``, in
+    the nested scope of its collective: ``ar_tail``, ``ar_stats`` and
+    ``ar_sse`` for the staged sync, ``ar_payload`` for the fused one."""
     out = run_with_devices("""
         import re, jax
         from repro.data import cambridge_data
         from repro.core.ibp import IBPHypers, SamplerSpec, build_sampler
         X, _, _ = cambridge_data(N=64, seed=9)
+        nested = {'staged': {'ar_tail', 'ar_stats', 'ar_sse'},
+                  'fused': {'ar_payload'}}
         for sync in ('staged', 'fused'):
             spec = SamplerSpec(P=4, K_max=12, K_tail=4, K_init=3, L=2,
                                data='shardmap', sync=sync)
@@ -129,9 +133,65 @@ def test_shardmap_step_carries_scopes():
             ars = re.findall(
                 r'= [^\\n]*? all-reduce\\([^\\n]*?op_name="([^"]*)"', hlo)
             assert ars and all('ibp_sync/' in n for n in ars), (sync, ars)
+            scopes = [re.search(r'ibp_sync/(ar_[a-z]+)/', n) for n in ars]
+            assert all(scopes), (sync, ars)
+            assert {m.group(1) for m in scopes} == nested[sync], (sync, ars)
             print('OK', sync, len(ars))
     """, n_devices=4)
     assert "OK staged" in out and "OK fused" in out
+
+
+def test_shardmap_data_placed_per_device():
+    """Under data='shardmap' each device receives its own rows straight
+    from host memory and the sampler keeps no single-device array of X's
+    size; the initial state and three steps are bitwise those of the
+    earlier placement (X gathered on the default device, ``init`` run on
+    that copy), and a state from ``init`` runs the one compiled step."""
+    out = run_with_devices("""
+        import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as PS
+        from repro.data import cambridge_data
+        from repro.core.ibp import IBPHypers, SamplerSpec, build_sampler
+        from repro.core.ibp.hybrid import init_hybrid
+        X, _, _ = cambridge_data(N=400, seed=5)
+        spec = SamplerSpec(P=4, K_max=12, K_tail=4, K_init=4, L=2,
+                           data='shardmap')
+        s = build_sampler(spec, IBPHypers(), X)
+        N, D = s.N, s.D
+        for name, v in vars(s).items():
+            if isinstance(v, jax.Array) and v.size >= N * D:
+                assert len(v.sharding.device_set) == 4, (name, v.sharding)
+        assert {sh.data.shape for sh in s._Xn.addressable_shards} == {
+            (N // 4, D)}
+        compiles = []
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda ev, secs, **k: compiles.append(ev)
+            if ev == '/jax/core/compile/backend_compile_duration' else None)
+        key = jax.random.key(11)
+        states = [s.init(key)]
+        n0 = len(compiles)
+        for _ in range(3):
+            states.append(s.step(*states[-1]))
+        jax.block_until_ready(states[-1])
+        assert len(compiles) - n0 == 1, compiles[n0:]    # the step, once
+        # the earlier placement, composed from the same building blocks
+        Xs_old = jnp.asarray(s.X_global.reshape(4, N // 4, D))
+        gs, ss = init_hybrid(key, Xs_old, spec.K_max, K_tail=spec.K_tail,
+                             alpha=spec.alpha, sigma_x=spec.sigma_x,
+                             sigma_a=spec.sigma_a, K_init=spec.K_init)
+        st = s.from_canonical(ss)
+        Xn_old = jax.device_put(jnp.asarray(s.X_global),
+                                NamedSharding(s.mesh, PS('data')))
+        for i, new in enumerate(states):
+            if i:
+                gs, *st = s._fns.step(Xn_old, gs, *st)
+            for x, y in zip(jax.tree.leaves(new), jax.tree.leaves((gs, st))):
+                if jnp.issubdtype(x.dtype, jax.dtypes.prng_key):
+                    x, y = jax.random.key_data(x), jax.random.key_data(y)
+                np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        print('OK placed')
+    """, n_devices=4)
+    assert "OK placed" in out
 
 
 def test_moe_a2a_matches_gather_dispatch():
