@@ -68,7 +68,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels._backend import default_interpret, on_tpu
 from repro.kernels.collapsed_row import collapsed_row_flip
+from repro.kernels.tail_scan import tail_scan_pallas
 
 from . import math as ibm
 from .state import IBPHypers, IBPState
@@ -265,6 +267,36 @@ class _PackedCarry(NamedTuple):
     ubase: Array      # () int32 — first row-offset covered by ``ubuf``
 
 
+def _key_chain(key: Array, n_rows: int) -> tuple[Array, Array, Array]:
+    """The row scan's serial key chain: four-way split per row, as the
+    oracle's row step does. Returns (chain_data (n_rows + 1, ...) — the
+    carry key's data after j rows —, the bit-flip keys' data, the dish
+    keys)."""
+    def key_step(k, _):
+        k2, kbits, kdish, _kslot = jax.random.split(k, 4)
+        return k2, (jax.random.key_data(k2), jax.random.key_data(kbits),
+                    kdish)
+
+    _, (chain_next, kbits_data, kdish_all) = jax.lax.scan(
+        key_step, key, None, length=n_rows)
+    chain_data = jnp.concatenate(
+        [jax.random.key_data(key)[None], chain_next])
+    return chain_data, kbits_data, kdish_all
+
+
+def _logit_uniforms(kbits_data: Array, base: Array, rows: int, K: int,
+                    dtype) -> Array:
+    """Logit-uniform flip thresholds for row offsets [base, base + rows)
+    from the chain's bit-flip keys."""
+    kb = jax.lax.dynamic_slice_in_dim(kbits_data, base, rows, 0)
+    uu = jax.vmap(
+        lambda kd: jax.random.uniform(
+            jax.random.wrap_key_data(kd), (K,), dtype=dtype)
+    )(kb)
+    uu = jnp.clip(uu, 1e-7, 1.0 - 1e-7)
+    return jnp.log(uu) - jnp.log1p(-uu)
+
+
 @partial(jax.jit, static_argnames=("N", "birth", "B", "refresh_every",
                                    "drift_tol", "flip_flavor",
                                    "u_chunk_rows", "carry_g"))
@@ -350,25 +382,11 @@ def _packed_scan(
     chunked = u_chunk < n_rows
     j_cap = jnp.asarray(n_rows - u_chunk, jnp.int32)
 
-    def key_step(k, _):
-        k2, kbits, kdish, _kslot = jax.random.split(k, 4)
-        return k2, (jax.random.key_data(k2), jax.random.key_data(kbits),
-                    kdish)
-
-    _, (chain_next, kbits_data, kdish_all) = jax.lax.scan(
-        key_step, key, None, length=n_rows)
-    chain_data = jnp.concatenate(
-        [jax.random.key_data(key)[None], chain_next])
+    chain_data, kbits_data, kdish_all = _key_chain(key, n_rows)
 
     def gen_u(base):
         """Logit-uniform block for row offsets [base, base + u_chunk)."""
-        kb = jax.lax.dynamic_slice_in_dim(kbits_data, base, u_chunk, 0)
-        uu = jax.vmap(
-            lambda kd: jax.random.uniform(
-                jax.random.wrap_key_data(kd), (K_can,), dtype=X.dtype)
-        )(kb)
-        uu = jnp.clip(uu, 1e-7, 1.0 - 1e-7)
-        return jnp.log(uu) - jnp.log1p(-uu)
+        return _logit_uniforms(kbits_data, base, u_chunk, K_can, X.dtype)
 
     # single-block case: the whole buffer is a loop-closure constant and
     # the carry's ubuf is an empty placeholder (cond-free hot loop)
@@ -678,6 +696,54 @@ def collapsed_row_scan(
         carry_g=pack,
     )
     return Z, active, ZtZ, ZtX, m, n_refresh, n_sat
+
+
+def tail_path(backend: str, pack: bool) -> str:
+    """The path the hybrid tail's row scan takes: ``"fused"`` — the
+    whole scan as one Pallas kernel (``fused_tail_scan``) — on a TPU for
+    the fast backend's carried-G path, else ``"scan"``
+    (``collapsed_row_scan``)."""
+    return "fused" if on_tpu() and backend == "fast" and pack else "scan"
+
+
+def fused_tail_scan(
+    Z: Array,
+    active: Array,
+    ZtZ: Array,
+    ZtX: Array,
+    m: Array,
+    X: Array,
+    key: Array,
+    alpha: Array,
+    sx: Array,
+    sa: Array,
+    *,
+    N: float,
+    refresh_every: int = DEFAULT_REFRESH,
+    drift_tol: float = DEFAULT_DRIFT_TOL,
+) -> tuple[Array, Array, Array, Array, Array, Array, Array]:
+    """The hybrid tail's row scan as one kernel launch (DESIGN.md §12,
+    "The fused tail"): ``collapsed_row_scan(birth="mh", backend="fast",
+    pack=True)`` with the row loop in ``kernels/tail_scan``.
+
+    The rows' random numbers are drawn here, in XLA, exactly as
+    ``_packed_scan`` draws them — the key chain, the logit-uniforms and
+    the MH draws — so both paths (and the benchmark's reference, which
+    replays the chain) see the same numbers. Returns (Z, active, ZtZ,
+    ZtX, m, n_refresh, n_sat) with ``collapsed_row_scan``'s meaning.
+    """
+    n_rows, _ = X.shape
+    _, kbits_data, kdish_all = _key_chain(key, n_rows)
+    u = _logit_uniforms(kbits_data, jnp.zeros((), jnp.int32), n_rows,
+                        Z.shape[1], X.dtype)
+    j_prop, u_acc = jax.vmap(lambda kd: _mh_draws(kd, alpha / N, X.dtype))(
+        kdish_all)
+    Zo, active, ZtZ, ZtX, m, n_refresh, n_sat = tail_scan_pallas(
+        Z, active, ZtZ, ZtX, m, X, u, j_prop, u_acc, sx, sa,
+        N=N, refresh_every=refresh_every, drift_tol=drift_tol,
+        interpret=default_interpret(),
+    )
+    return Zo.astype(Z.dtype), active, ZtZ, ZtX, m, n_refresh, n_sat
 
 
 def _finish_sweep(state, X, hyp, Z, active, ZtZ, ZtX, m,

@@ -65,7 +65,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from . import math as ibm
-from .collapsed import DEFAULT_REFRESH, collapsed_row_scan
+from .collapsed import (DEFAULT_REFRESH, collapsed_row_scan, fused_tail_scan,
+                        tail_path)
 from .sweeps import uncollapsed_sweep
 
 Array = jax.Array
@@ -182,13 +183,15 @@ def _tail_sub_iteration(
 
     ``collapsed_backend`` selects the row-step implementation (DESIGN.md
     §12): the K_tail ≤ 8 problem is too small for the O(K²) carry to
-    matter, but the "pallas" flavor moves the K-sequential bit-flip
-    recurrence into the ``collapsed_row`` kernel, keeping the whole tail
-    recurrence VMEM-resident on TPU. ``k_live_pack`` (the spec's
-    ``k_live_buckets`` knob) selects the unified core's carried-G float
-    path — in-jit the block is the full K_tail width either way, so what
-    the tail gains from ``pack=True`` is the carried G = HHᵀ (DESIGN.md
-    §12).
+    matter, but the "pallas" flavor moves each row's K-sequential
+    bit-flip recurrence into the ``collapsed_row`` kernel (only the
+    flips run in VMEM; the row scan around them stays an XLA loop).
+    ``k_live_pack`` (the spec's ``k_live_buckets`` knob) selects the
+    unified core's carried-G float path — in-jit the block is the full
+    K_tail width either way, so what the tail gains from ``pack=True``
+    is the carried G = HHᵀ (DESIGN.md §12). On a TPU the "fast" backend
+    with ``pack=True`` runs the whole row scan as one kernel launch
+    (``collapsed.fused_tail_scan``; ``collapsed.tail_path`` decides).
 
     Returns (Z_tail, tail_active, n_sat, n_refresh): ``n_sat`` counts
     rows whose accepted MH birth was vetoed purely by K_tail capacity —
@@ -201,15 +204,21 @@ def _tail_sub_iteration(
         m_t = jnp.sum(Z_tail, axis=0)
         ZtZ_t = Z_tail.T @ Z_tail
         ZtR = Z_tail.T @ R
-        # u_chunk_rows=n_rows: under chains="vmap" this entry is batched —
-        # the chunked refill would lower to select and regenerate per row
-        Z_tail, tail_active, _, _, m_t, n_refresh, n_sat = collapsed_row_scan(
-            Z_tail, tail_active, ZtZ_t, ZtR, m_t, R, key,
-            gs.alpha, gs.sigma_x, gs.sigma_a,
-            N=N_global, birth="mh", backend=collapsed_backend,
-            refresh_every=chol_refresh, pack=k_live_pack,
-            u_chunk_rows=R.shape[0],
-        )
+        args = (Z_tail, tail_active, ZtZ_t, ZtR, m_t, R, key,
+                gs.alpha, gs.sigma_x, gs.sigma_a)
+        if tail_path(collapsed_backend, k_live_pack) == "fused":
+            out = fused_tail_scan(*args, N=N_global,
+                                  refresh_every=chol_refresh)
+        else:
+            # u_chunk_rows=n_rows: under chains="vmap" this entry is
+            # batched — the chunked refill would lower to select and
+            # regenerate per row
+            out = collapsed_row_scan(
+                *args, N=N_global, birth="mh", backend=collapsed_backend,
+                refresh_every=chol_refresh, pack=k_live_pack,
+                u_chunk_rows=R.shape[0],
+            )
+        Z_tail, tail_active, _, _, m_t, n_refresh, n_sat = out
         # prune dead tail columns
         tail_active = tail_active * (m_t > 0.5)
         Z_tail = Z_tail * tail_active[None, :]

@@ -60,6 +60,7 @@ from repro.core.ibp import convergence
 from repro.core.ibp.api import DRIVERS
 from repro.core.ibp.collapsed import (
     DEFAULT_REFRESH as DEFAULT_CHOL_REFRESH,
+    tail_path,
 )
 from repro.core.ibp.hybrid import HybridGlobal, HybridShard
 from repro.core.ibp.predict import (
@@ -523,6 +524,9 @@ class MCMCDriver:
         # over a mesh: it scores one device's copy of the parameters
         gs_ev = (gs if self.sampler.mesh is None
                  else jax.device_put(gs, jax.devices()[0]))
+        # which path the tail's row scan took: the fused kernel or the scan
+        path = tail_path(self.spec.collapsed_backend,
+                         self.spec.k_live_buckets == "on")
         if self._chain_axis:
             C = ss.Z.shape[0]
             Z = ss.Z.reshape(C, self.N, -1)
@@ -547,6 +551,7 @@ class MCMCDriver:
                 "tail_refresh": int(jnp.max(gs.tail_refresh)),
                 "tail_refresh_chains": [
                     int(r) for r in np.asarray(gs.tail_refresh)],
+                "tail_path": path,
             }
             if self.X_eval is not None:
                 ev = jax.vmap(
@@ -570,6 +575,7 @@ class MCMCDriver:
                 "K_tail": int(self.spec.K_tail),
                 "tail_sat": int(gs.tail_sat),
                 "tail_refresh": int(gs.tail_refresh),
+                "tail_path": path,
             }
             if self.X_eval is not None:
                 rec["joint_ll_eval"] = float(heldout_joint_loglik(

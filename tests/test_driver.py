@@ -291,3 +291,22 @@ def test_checkpoint_without_tail_refresh_restores_it_at_zero(data,
     # the old checkpoint's count restarts at 0: only iteration 3's remain
     assert int(gs_old.tail_refresh) == (int(gs_new.tail_refresh)
                                         - int(blob["gs"].tail_refresh))
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_eval_record_names_the_tail_path(data, tmp_path, monkeypatch,
+                                         fused):
+    """Every eval record says which path the tail's row scan took: the
+    fused kernel (on a TPU; forced here, in interpret mode) or the scan.
+    The fused run counts the same refreshes as the scan."""
+    from repro.core.ibp import collapsed
+
+    if fused:
+        monkeypatch.setattr(collapsed, "on_tpu", lambda: True)
+    drv = MCMCDriver(data, _refresh_cfg(tmp_path, 2), IBPHypers())
+    gs, _ = drv.run()
+    assert [r["tail_path"] for r in drv.history] == (
+        ["fused" if fused else "scan"] * 2)
+    cfg = drv.cfg
+    per_iter = cfg.L * ((data.shape[0] // cfg.P) // cfg.chol_refresh)
+    assert int(gs.tail_refresh) >= cfg.n_iters * per_iter

@@ -19,9 +19,14 @@ from repro.kernels.collapsed_row.kernel import collapsed_row_flip_pallas
 from repro.kernels.feature_stats import feature_stats_core
 from repro.kernels.gaussian_sse import gaussian_sse_core
 from repro.kernels.gibbs_flip import gibbs_flip_core
+from repro.kernels.tail_scan import tail_scan_pallas
 
 # the paper's instance and the widest width the kernels are written for
 SIZES = {"paper": (1000, 36, 32), "wide": (8192, 1024, 64)}
+# the fused tail's rows on p′: the paper's instance, the four-chip fit,
+# and a million rows over four chips (the row-block grid keeps VMEM
+# bounded whatever the rows)
+TAIL_ROWS = {"paper": 180, "dp4": 2250, "million": 250_000}
 
 
 @pytest.fixture(scope="module")
@@ -111,3 +116,47 @@ def test_pallas_sweep_carries_its_scope_for_v5e(one_chip, monkeypatch):
                        r"custom_call_target=\"tpu_custom_call\"[^\n]*?"
                        r"op_name=\"([^\"]*)\"", hlo)
     assert calls and all("ibp_sweep/" in c for c in calls), calls
+
+
+@pytest.mark.parametrize("rows", sorted(TAIL_ROWS))
+def test_tail_scan_compiles_for_v5e(rows, one_chip):
+    n, K, D = TAIL_ROWS[rows], 8, 36
+    fn = lambda *a: tail_scan_pallas(*a, N=4.0 * n, refresh_every=64,
+                                     drift_tol=1e-2, interpret=False)
+    shapes = [(n, K), (K,), (K, K), (K, D), (K,), (n, D), (n, K), (n,),
+              (n,), (), ()]
+    compiled = _lower(fn, shapes, one_chip).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_tail_carries_its_scope_for_v5e(one_chip, monkeypatch):
+    """On a TPU the hybrid tail takes the fused path: its row scan is
+    one ``ibp_tail_scan`` custom call, inside the ``ibp_tail`` scope."""
+    import re
+
+    from repro.core.ibp import collapsed, hybrid
+
+    monkeypatch.setattr(collapsed, "on_tpu", lambda: True)
+    monkeypatch.setattr(collapsed, "default_interpret", lambda: False)
+    n, D, K_max, K_tail = 180, 36, 32, 8
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    gs = hybrid.HybridGlobal(
+        A=f32(K_max, D), pi=f32(K_max), active=f32(K_max), alpha=f32(),
+        sigma_x=f32(), sigma_a=f32(), key=key, p_prime=i32, it=i32,
+        overflow=i32, tail_sat=i32, tail_refresh=i32)
+
+    def tail(X_p, Z, Z_tail, tail_active, gs, key):
+        return hybrid._tail_sub_iteration(
+            X_p, Z, Z_tail, tail_active, gs, 900.0, key,
+            collapsed_backend="fast", chol_refresh=64, k_live_pack=True)
+
+    hlo = jax.jit(tail).lower(f32(n, D), f32(n, K_max), f32(n, K_tail),
+                              f32(K_tail), gs, key).compile().as_text()
+    calls = re.findall(r"= [^\n]*? custom-call\([^\n]*?"
+                       r"custom_call_target=\"tpu_custom_call\"[^\n]*?"
+                       r"op_name=\"([^\"]*)\"", hlo)
+    assert len(calls) == 1 and "ibp_tail/" in calls[0], calls
+    assert "ibp_tail_scan" in hlo
