@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.collapsed_row import (
-    collapsed_row_flip,
     collapsed_row_flip_fast,
     collapsed_row_flip_ref,
 )
@@ -85,8 +84,8 @@ def bench_feature_stats(N, K, D):
 def bench_collapsed_row(N, K, D):
     """The K-sequential collapsed bit-flip recurrence, scanned over N rows.
 
-    Correctness: Pallas kernel (interpret on CPU) must match the jnp
-    oracle bitwise at this shape. Perf: the ref (full-K, mean-carry) vs
+    Correctness: the fast flavor must keep the jnp oracle's decisions
+    at this shape. Perf: the ref (full-K, mean-carry) vs
     fast (packed-active, rss/rH-carry) flavors over an N-row scan — the
     "ref-vs-fast" column of the perf trajectory at the recurrence level.
     """
@@ -112,9 +111,7 @@ def bench_collapsed_row(N, K, D):
     v, q, mean = start(z0)
     base = (M, H, x, z0, v, q, mean, u, mm, act, Nf, i2)
     zr, *_ = collapsed_row_flip_ref(*base)
-    zp, *_ = collapsed_row_flip(*base, flavor="pallas")
     zf, *_ = collapsed_row_flip_fast(*base)
-    assert bool(jnp.all(zr == zp)), "pallas != ref"  # identical arithmetic
     # the packed flavor's float path may round a boundary accept differently
     # (documented; tests budget the same) — don't fail CI on one such bit
     assert int(jnp.sum(zr != zf)) <= 2, "fast diverged from ref beyond budget"

@@ -6,8 +6,8 @@ K_max=32, K_tail=8):
 
   fit      ``repro.launch.mcmc.main`` with the default knobs (jnp sweep,
            fast collapsed tail), harvesting a SampleBank
-  pallas   a short fit with both Pallas kernels in the sampler
-  kernels  each of the four Pallas kernels, compiled, against its
+  pallas   a short fit with the Pallas sweep in the sampler
+  kernels  each of the three Pallas kernels, compiled, against its
            ``ref.py`` oracle at the paper's and a wide width
   carry    one ``fast`` and one ``ref`` collapsed sweep from one state,
            and the carried factor's refresh count
@@ -150,8 +150,7 @@ def phase_fit() -> str:
 
 def phase_pallas_fit() -> None:
     run_fit("pallas_fit", PALLAS_ITERS, PALLAS_EVAL,
-            {"driver": "vmap", "backend": "pallas",
-             "collapsed-backend": "pallas"}, bands=True)
+            {"driver": "vmap", "backend": "pallas"}, bands=True)
 
 
 # ---------------------------------------------------------------------------
@@ -170,24 +169,6 @@ def _dense_inputs(N, D, K, seed):
     return X, Z, A, act, lpi, u
 
 
-def _collapsed_row_inputs(K, D, seed):
-    """A consistent (M, H, v, q, mean) carry for one row, as in
-    tests/test_kernels.py."""
-    rng = np.random.default_rng(seed)
-    act = np.ones(K, np.float32)
-    Zb = (rng.random((5 * K, K)) < 0.3).astype(np.float32)
-    M = np.linalg.inv(Zb.T @ Zb + 0.7 * np.eye(K)).astype(np.float32)
-    H = (M @ (Zb.T @ rng.standard_normal((5 * K, D)))).astype(np.float32)
-    x = rng.standard_normal(D).astype(np.float32)
-    z = (rng.random(K) < 0.4).astype(np.float32)
-    v = (M @ z).astype(np.float32)
-    u = (rng.standard_normal(K) * 2).astype(np.float32)
-    args = [jnp.asarray(a) for a in (M, H, x, z, v, np.float32(z @ v),
-                                     (z @ H).astype(np.float32), u,
-                                     Zb.sum(0), act)]
-    return args + [jnp.float32(8 * K), jnp.float32(0.5)]
-
-
 def _n_outside(got, want, rtol, atol) -> int:
     got, want = np.asarray(got), np.asarray(want)
     return int(np.sum(np.abs(got - want) > atol + rtol * np.abs(want)))
@@ -195,10 +176,8 @@ def _n_outside(got, want, rtol, atol) -> int:
 
 def phase_kernels() -> None:
     """Each kernel compiled against its oracle, with the tolerances of
-    tests/test_kernels.py: gibbs_flip and collapsed_row exactly,
+    tests/test_kernels.py: gibbs_flip exactly,
     feature_stats and gaussian_sse to f32 rounding."""
-    from repro.kernels.collapsed_row import (collapsed_row_flip_pallas,
-                                             collapsed_row_flip_ref)
     from repro.kernels.feature_stats import (feature_stats_core,
                                              feature_stats_ref)
     from repro.kernels.gaussian_sse import gaussian_sse_core, gaussian_sse_ref
@@ -237,17 +216,6 @@ def phase_kernels() -> None:
         if rel > 1e-5:
             failures.append(f"gaussian_sse/{size}: rel err {rel:.3e}")
 
-        args = _collapsed_row_inputs(K, D, seed=K + D)
-        ref = collapsed_row_flip_ref(*args)
-        pal = collapsed_row_flip_pallas(*args, interpret=False)
-        names = ("z", "v", "q", "mean")
-        bad = {n: _n_outside(p, r, 0.0, 0.0)
-               for n, p, r in zip(names, pal, ref)}
-        say(phase="kernels", kernel="collapsed_row", size=size,
-            **{f"mismatched_{n}": c for n, c in bad.items()},
-            max_abs_mean=float(jnp.max(jnp.abs(pal[3] - ref[3]))))
-        if any(bad.values()):
-            failures.append(f"collapsed_row/{size}: {bad}")
     say(phase="kernels", wall_s=f"{time.time() - t0:.3f}",
         peak_bytes_in_use=peak_bytes())
     require(not failures, "kernels off their oracles: " + "; ".join(failures))

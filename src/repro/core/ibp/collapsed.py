@@ -28,9 +28,6 @@ Two row-step backends (DESIGN.md §12), selected by ``backend=``:
   ‖M W p − p‖_∞ against the exactly maintained integer sufficient
   statistics, and the downdate's loss-of-positivity canary) force an
   early refresh when the carry degrades.
-* ``"pallas"`` — the fast path with the K-sequential bit-flip recurrence
-  executed by the ``kernels/collapsed_row`` Pallas kernel (VMEM-resident
-  carry; compiled on TPU, interpret elsewhere).
 
 There is ONE implementation of the carried row step: ``_packed_scan``,
 which runs the carry PACKED to a block of B columns (the unified core,
@@ -42,16 +39,22 @@ the carry (moved by the rank-two corrections matching each H move) to
 keep the strict O(K²+KD) row bound. ``collapsed_sweep`` picks the
 bucket host-side per sweep (and re-packs mid-sweep when a feature birth
 overflows the block — the overflowing row is re-run at the bigger
-bucket, so decisions stay on the oracle's trajectory).
-``k_live_buckets="off"`` is the TOP-BUCKET degenerate point of the same
-ladder: the identical packed core at B = K_max with the G carry
-disabled (``carry_g=False``), which is bitwise-identical to the
+bucket, so decisions stay on the oracle's trajectory). The serial
+sweep's ``k_live_buckets="off"`` is the TOP-BUCKET degenerate point of
+the same ladder: the identical packed core at B = K_max with the G
+carry disabled (``carry_g=False``), which is bitwise-identical to the
 pre-unification unpacked carry (the packed flip recomputes G = HHᵀ per
 row, exactly as the legacy ``_row_step_fast`` did). The in-jit entry
-``collapsed_row_scan`` (the hybrid tail) runs the same core at the full
-padded width — ``pack=True`` carries G, ``pack=False`` keeps the
-legacy float path. Packing is a pure permutation + refresh: decisions
-are ref-equivalent within a tiny boundary budget in every mode.
+``collapsed_row_scan`` runs the same core at the full padded width —
+``pack=True`` carries G, ``pack=False`` keeps the legacy float path.
+Packing is a pure permutation + refresh: decisions are ref-equivalent
+within a tiny boundary budget in every mode.
+
+The hybrid tail's row scan is chosen in one place, ``tail_row_scan``:
+on a TPU the fast backend runs the whole scan as one Pallas kernel
+(``fused_tail_scan``, ``kernels/tail_scan``); elsewhere, and for the
+ref backend, it is ``collapsed_row_scan`` with the G carry.
+``tail_path`` names which of the two runs.
 
 The MH new-dish move additionally reports a TAIL-SATURATION counter
 (``n_sat``): rows whose accepted birth proposal was rejected only for
@@ -79,7 +82,7 @@ Array = jax.Array
 
 J_MAX = 4  # truncation for per-row new-dish draws (P(j>4 | alpha/N) is negligible)
 
-COLLAPSED_BACKENDS = ("ref", "fast", "pallas")
+COLLAPSED_BACKENDS = ("ref", "fast")
 DEFAULT_REFRESH = 64    # exact refactorization cadence of the fast path
 DEFAULT_DRIFT_TOL = 1e-2  # probe-residual threshold forcing an early refresh
 PROBE_EVERY = 4         # drift-probe cadence within the refresh window
@@ -298,13 +301,12 @@ def _logit_uniforms(kbits_data: Array, base: Array, rows: int, K: int,
 
 
 @partial(jax.jit, static_argnames=("N", "birth", "B", "refresh_every",
-                                   "drift_tol", "flip_flavor",
-                                   "u_chunk_rows", "carry_g"))
+                                   "drift_tol", "u_chunk_rows", "carry_g"))
 def _packed_scan(
     Z, active, ZtZ, ZtX, m, X, key, alpha, sx, sa, start_row, *,
     N: float, birth: str, B: int, refresh_every: int,
-    drift_tol: float = DEFAULT_DRIFT_TOL, flip_flavor: str = "packed",
-    u_chunk_rows: int = U_CHUNK_ROWS, carry_g: bool = True,
+    drift_tol: float = DEFAULT_DRIFT_TOL, u_chunk_rows: int = U_CHUNK_ROWS,
+    carry_g: bool = True,
 ):
     """Packed row scan from ``start_row`` to the end of X — or to the
     first birth that does not fit the K_live block. THE single
@@ -345,9 +347,6 @@ def _packed_scan(
     m_p = m[cols]
     ratio = (sx / sa) ** 2
     Lt0, M0, H0 = _exact_factor(ZtZ_p, ZtX_p, active_p, ratio)
-    # the mean-form pallas flip never consumes G — skip the whole G carry
-    # (moves, refresh rebuild, probe term) at trace time for that flavor
-    carry_g = carry_g and flip_flavor != "pallas"
     G0 = ibm.dot(H0, H0.T) if carry_g else jnp.zeros((), X.dtype)
     inv2s2 = 0.5 / (sx**2)
 
@@ -504,7 +503,7 @@ def _packed_scan(
         )
         z, v, q, mean = collapsed_row_flip(
             M1, H1, x_n, z, v, q, mean, u, m_minus, active_m, N, inv2s2,
-            flavor=flip_flavor, G=G1 if carry_g else None,
+            flavor="packed", G=G1 if carry_g else None,
         )
 
         # ---- new dishes: canonical free capacity; placement must stay
@@ -657,14 +656,14 @@ def collapsed_row_scan(
     and ``n_sat`` the capacity-vetoed accepted MH births (the tail-
     saturation signal; 0 for ``birth="gibbs"``).
 
-    The fast/pallas backends run the ONE packed core at the full padded
+    The fast backend runs the ONE packed core at the full padded
     width (a static in-jit bucket: B = K; the bucketed B < K_max
     dispatch needs the host — ``collapsed_sweep``). ``pack`` selects the
     float path: ``True`` carries G = HHᵀ, removing the per-row O(K²D)
     GEMM from the packed flip (the hybrid tail's win); ``False`` keeps
     the legacy unpacked float path (G recomputed per row) — bitwise the
-    pre-unification ``k_live_buckets="off"`` carry. Ignored for
-    ``backend="ref"``.
+    pre-unification ``k_live_buckets="off"`` carry of the serial sweep.
+    Ignored for ``backend="ref"``.
 
     ``u_chunk_rows=None`` keeps the historical defaults: the full
     (n_rows, K) uniform hoist for ``pack=True`` and the chunked
@@ -689,7 +688,6 @@ def collapsed_row_scan(
         Z, active, ZtZ, ZtX, m, X, key, alpha, sx, sa, 0,
         N=N, birth=birth, B=Z.shape[1], refresh_every=refresh_every,
         drift_tol=drift_tol,
-        flip_flavor="pallas" if backend == "pallas" else "packed",
         u_chunk_rows=(u_chunk_rows if u_chunk_rows is not None
                       else n_rows if pack
                       else min(U_CHUNK_ROWS, n_rows)),
@@ -698,12 +696,30 @@ def collapsed_row_scan(
     return Z, active, ZtZ, ZtX, m, n_refresh, n_sat
 
 
-def tail_path(backend: str, pack: bool) -> str:
-    """The path the hybrid tail's row scan takes: ``"fused"`` — the
-    whole scan as one Pallas kernel (``fused_tail_scan``) — on a TPU for
-    the fast backend's carried-G path, else ``"scan"``
-    (``collapsed_row_scan``)."""
-    return "fused" if on_tpu() and backend == "fast" and pack else "scan"
+def tail_path(backend: str) -> str:
+    """The path the hybrid tail's row scan takes for ``backend`` on this
+    platform: ``"fused"`` — the whole scan as one Pallas kernel
+    (``fused_tail_scan``) — on a TPU for the fast backend, else
+    ``"scan"`` (``collapsed_row_scan``)."""
+    return "fused" if on_tpu() and backend == "fast" else "scan"
+
+
+def tail_row_scan(backend: str, refresh_every: int = DEFAULT_REFRESH):
+    """The hybrid tail's row scan — the ONE place that chooses it
+    (DESIGN.md §12). Returns ``scan(Z, active, ZtZ, ZtX, m, X, key,
+    alpha, sx, sa, *, N)`` -> (Z, active, ZtZ, ZtX, m, n_refresh,
+    n_sat), MH births throughout: the fused kernel where ``tail_path``
+    says ``"fused"``, else ``collapsed_row_scan`` with the carried G
+    (for ``"ref"``, the ``_row_step`` oracle)."""
+    if backend not in COLLAPSED_BACKENDS:
+        raise ValueError(f"backend={backend!r} not in {COLLAPSED_BACKENDS}")
+    if tail_path(backend) == "fused":
+        return partial(fused_tail_scan, refresh_every=refresh_every)
+    # pack=True hoists every row's uniforms (u_chunk_rows = n_rows): under
+    # chains="vmap" the tail is batched, and the chunked refill's cond
+    # would lower to select and regenerate a whole block per row
+    return partial(collapsed_row_scan, birth="mh", backend=backend,
+                   refresh_every=refresh_every, pack=True)
 
 
 def fused_tail_scan(
@@ -810,8 +826,8 @@ def _collapsed_sweep_jit(
     backend: str = "ref",
     refresh_every: int = DEFAULT_REFRESH,
 ) -> IBPState:
-    """One fully-jitted collapsed sweep (ref, or the unified fast/pallas
-    core at the TOP bucket: B = K_max, legacy no-G float path)."""
+    """One fully-jitted collapsed sweep (ref, or the unified fast core
+    at the TOP bucket: B = K_max, legacy no-G float path)."""
     N, D = X.shape
     Z, active = state.Z, state.active
     m, ZtZ, ZtX, _ = _sweep_stats(Z, active, X)
@@ -835,8 +851,8 @@ def _sweep_stats(Z, active, X):
     return m, ZtZ, ZtX, jnp.sum(active)
 
 
-@partial(jax.jit, static_argnames=("hyp", "backend", "refresh_every", "B"))
-def _packed_sweep_jit(state, X, hyp, backend, refresh_every, B):
+@partial(jax.jit, static_argnames=("hyp", "refresh_every", "B"))
+def _packed_sweep_jit(state, X, hyp, refresh_every, B):
     """One FUSED packed sweep attempt at bucket ``B``: stats + packed
     scan from row 0 + hyper-update finish, all in one dispatch.
 
@@ -856,7 +872,6 @@ def _packed_sweep_jit(state, X, hyp, backend, refresh_every, B):
         state.Z, state.active, ZtZ, ZtX, m, X, ksweep,
         state.alpha, state.sigma_x, state.sigma_a, 0,
         N=float(N), birth="gibbs", B=B, refresh_every=refresh_every,
-        flip_flavor="pallas" if backend == "pallas" else "packed",
     )
     done = _finish_sweep(state, X, hyp, Z, active, ZtZ2, ZtX2, m2,
                          key, kalpha, ksx, ksa)
@@ -868,7 +883,6 @@ def _collapsed_sweep_packed(
     state: IBPState,
     X: Array,
     hyp: IBPHypers,
-    backend: str,
     refresh_every: int,
     seg_log: list | None = None,
 ) -> IBPState:
@@ -891,14 +905,12 @@ def _collapsed_sweep_packed(
     N, D = X.shape
     K_max = state.Z.shape[1]
     buckets = ibm.live_buckets(K_max)
-    flavor = "pallas" if backend == "pallas" else "packed"
     kp = int(jnp.sum(state.active))
     B = ibm.pick_bucket(buckets, kp, PACK_HEADROOM)
     if seg_log is not None:
         seg_log.append((B, 0))
     done, raw, ovf_row = _packed_sweep_jit(
-        state, X, hyp=hyp, backend=backend,
-        refresh_every=refresh_every, B=B)
+        state, X, hyp=hyp, refresh_every=refresh_every, B=B)
     ovf = int(ovf_row)
     if ovf < 0:
         return done
@@ -914,7 +926,6 @@ def _collapsed_sweep_packed(
         Z, active, ZtZ, ZtX, m, _, _, ksweep, ovf_row = _packed_scan(
             Z, active, ZtZ, ZtX, m, X, ksweep, alpha, sx, sa, row,
             N=float(N), birth="gibbs", B=B, refresh_every=refresh_every,
-            flip_flavor=flavor,
         )
         # ONE host round-trip per segment: the overflow row and the
         # next bucket choice's occupancy fetch together
@@ -937,7 +948,7 @@ def collapsed_sweep(
     """One full collapsed Gibbs sweep over all rows + hyperparameter updates.
 
     ``k_live_buckets`` selects occupancy-adaptive packing for the
-    fast/pallas backends (DESIGN.md §12): ``"on"`` (default) runs the
+    fast backend (DESIGN.md §12): ``"on"`` (default) runs the
     unified packed core on the live K⁺ bucket via the host-dispatched
     packed scan; ``"off"`` runs the SAME core at the top bucket
     (B = K_max, G carry disabled) in one fully-jitted sweep — bitwise
@@ -953,5 +964,5 @@ def collapsed_sweep(
     if backend == "ref" or k_live_buckets == "off":
         return _collapsed_sweep_jit(state, X, hyp, backend=backend,
                                     refresh_every=refresh_every)
-    return _collapsed_sweep_packed(state, X, hyp, backend, refresh_every,
+    return _collapsed_sweep_packed(state, X, hyp, refresh_every,
                                    seg_log=seg_log)

@@ -33,12 +33,13 @@ chain axis; ``vmap`` — C chains vmapped over the full iteration;
 data layout (``vmap`` — P shards simulated by vmap, psum == sum over
 the shard axis; ``shardmap`` — shard_map over a mesh data axis, psum ==
 jax.lax.psum, the production path). ``build_hybrid_fns(spec, hyp, ...)``
-is the ONE construction entry point: it reads every kernel knob
-(``L``, ``backend``, ``collapsed_backend``, ``chol_refresh``, ``sync``)
-off the spec and returns jitted ``(step, stale)`` functions for the
-requested layout — the old per-backend entry points
-(``hybrid_iteration_vmap`` / ``_multichain`` / ``hybrid_stale_pass`` /
-``make_hybrid_iteration_shardmap``) are subsumed by spec layouts.
+is the ONE construction entry point: it reads the kernel knobs
+(``L``, ``backend``, ``sync``) off the spec, takes the tail's row scan
+once from ``collapsed.tail_row_scan`` and returns jitted ``(step,
+stale)`` functions for the requested layout — the old per-backend
+entry points (``hybrid_iteration_vmap`` / ``_multichain`` /
+``hybrid_stale_pass`` / ``make_hybrid_iteration_shardmap``) are
+subsumed by spec layouts.
 
 The ``stale`` function is the bounded-staleness knob (DESIGN.md §10):
 sub-iterations only, no master sync (and, on a mesh, no collectives at
@@ -57,6 +58,7 @@ protocol and owns mesh creation + data placement.
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Callable
 from functools import partial
 from typing import Any
 
@@ -65,8 +67,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from . import math as ibm
-from .collapsed import (DEFAULT_REFRESH, collapsed_row_scan, fused_tail_scan,
-                        tail_path)
+from .collapsed import tail_row_scan
 from .sweeps import uncollapsed_sweep
 
 Array = jax.Array
@@ -175,23 +176,14 @@ def _tail_sub_iteration(
     gs: HybridGlobal,
     N_global: float,
     key: Array,
-    collapsed_backend: str = "ref",
-    chol_refresh: int = DEFAULT_REFRESH,
-    k_live_pack: bool = False,
+    row_scan: Callable,
 ) -> tuple[Array, Array, Array, Array]:
     """Collapsed Gibbs + MH births on the tail (runs on p' only).
 
-    ``collapsed_backend`` selects the row-step implementation (DESIGN.md
-    §12): the K_tail ≤ 8 problem is too small for the O(K²) carry to
-    matter, but the "pallas" flavor moves each row's K-sequential
-    bit-flip recurrence into the ``collapsed_row`` kernel (only the
-    flips run in VMEM; the row scan around them stays an XLA loop).
-    ``k_live_pack`` (the spec's ``k_live_buckets`` knob) selects the
-    unified core's carried-G float path — in-jit the block is the full
-    K_tail width either way, so what the tail gains from ``pack=True``
-    is the carried G = HHᵀ (DESIGN.md §12). On a TPU the "fast" backend
-    with ``pack=True`` runs the whole row scan as one kernel launch
-    (``collapsed.fused_tail_scan``; ``collapsed.tail_path`` decides).
+    ``row_scan`` is the tail's row scan from ``collapsed.tail_row_scan``
+    (DESIGN.md §12): on a TPU with the fast backend the whole scan is
+    one kernel launch (``collapsed.fused_tail_scan``), elsewhere the XLA
+    scan of the carried factor (``collapsed.collapsed_row_scan``).
 
     Returns (Z_tail, tail_active, n_sat, n_refresh): ``n_sat`` counts
     rows whose accepted MH birth was vetoed purely by K_tail capacity —
@@ -204,21 +196,9 @@ def _tail_sub_iteration(
         m_t = jnp.sum(Z_tail, axis=0)
         ZtZ_t = Z_tail.T @ Z_tail
         ZtR = Z_tail.T @ R
-        args = (Z_tail, tail_active, ZtZ_t, ZtR, m_t, R, key,
-                gs.alpha, gs.sigma_x, gs.sigma_a)
-        if tail_path(collapsed_backend, k_live_pack) == "fused":
-            out = fused_tail_scan(*args, N=N_global,
-                                  refresh_every=chol_refresh)
-        else:
-            # u_chunk_rows=n_rows: under chains="vmap" this entry is
-            # batched — the chunked refill would lower to select and
-            # regenerate per row
-            out = collapsed_row_scan(
-                *args, N=N_global, birth="mh", backend=collapsed_backend,
-                refresh_every=chol_refresh, pack=k_live_pack,
-                u_chunk_rows=R.shape[0],
-            )
-        Z_tail, tail_active, _, _, m_t, n_refresh, n_sat = out
+        Z_tail, tail_active, _, _, m_t, n_refresh, n_sat = row_scan(
+            Z_tail, tail_active, ZtZ_t, ZtR, m_t, R, key,
+            gs.alpha, gs.sigma_x, gs.sigma_a, N=N_global)
         # prune dead tail columns
         tail_active = tail_active * (m_t > 0.5)
         Z_tail = Z_tail * tail_active[None, :]
@@ -241,10 +221,8 @@ def shard_sub_iterations(
     shard_idx: Array,
     N_global: float,
     L: int,
-    backend: str = "jnp",
-    collapsed_backend: str = "ref",
-    chol_refresh: int = DEFAULT_REFRESH,
-    k_live_pack: bool = False,
+    backend: str,
+    row_scan: Callable,
 ) -> tuple[Array, Array, Array, Array, Array]:
     """L sub-iterations of the paper's inner loop on one shard (the
     shard_map layouts: the shard is this device, so the ``cond`` on p'
@@ -266,11 +244,7 @@ def shard_sub_iterations(
         def with_tail(args):
             Z_tail, tail_active, n_sat, n_ref = args
             Z_tail, tail_active, sat, ref = _tail_sub_iteration(
-                X_p, Z, Z_tail, tail_active, gs, N_global, kt,
-                collapsed_backend=collapsed_backend,
-                chol_refresh=chol_refresh,
-                k_live_pack=k_live_pack,
-            )
+                X_p, Z, Z_tail, tail_active, gs, N_global, kt, row_scan)
             return Z_tail, tail_active, n_sat + sat, n_ref + ref
 
         Z_tail, tail_active, n_sat, n_ref = jax.lax.cond(
@@ -294,9 +268,7 @@ def _vmap_sub_iterations(
     N_global: float,
     L: int,
     backend: str,
-    collapsed_backend: str,
-    chol_refresh: int,
-    k_live_pack: bool,
+    row_scan: Callable,
 ) -> tuple[Array, Array, Array, Array, Array]:
     """L sub-iterations of the paper's inner loop over the P simulated
     shards (the data-vmap layouts; leading shard axis on X_shards, Z,
@@ -326,8 +298,7 @@ def _vmap_sub_iterations(
                       keys[:, 0])
         Zt, ta, sat, ref = _tail_sub_iteration(
             X_pp, Z[pp], Z_tail[pp], tail_active[pp], gs, N_global,
-            keys[pp, 1], collapsed_backend=collapsed_backend,
-            chol_refresh=chol_refresh, k_live_pack=k_live_pack,
+            keys[pp, 1], row_scan,
         )
         return (Z, Z_tail.at[pp].set(Zt), tail_active.at[pp].set(ta),
                 n_sat + sat, n_ref + ref)
@@ -452,9 +423,7 @@ def _hybrid_iteration_body(
     L: int,
     N_g: float,
     backend: str,
-    collapsed_backend: str = "ref",
-    chol_refresh: int = DEFAULT_REFRESH,
-    k_live_pack: bool = False,
+    row_scan: Callable,
 ) -> tuple[HybridGlobal, HybridShard]:
     """One full hybrid iteration for ONE chain (vmap-simulated shards).
 
@@ -465,7 +434,7 @@ def _hybrid_iteration_body(
     """
     Z, Z_tail, tail_active, n_sat, n_ref = _vmap_sub_iterations(
         X_shards, ss.Z, ss.Z_tail, ss.tail_active, gs, N_g, L, backend,
-        collapsed_backend, chol_refresh, k_live_pack,
+        row_scan,
     )
     return _vmap_sync(X_shards, gs, Z, Z_tail, tail_active, n_sat, n_ref,
                       hyp, N_g)
@@ -551,9 +520,7 @@ def _hybrid_stale_body(
     L: int,
     N_g: float,
     backend: str,
-    collapsed_backend: str,
-    chol_refresh: int,
-    k_live_pack: bool = False,
+    row_scan: Callable,
 ) -> tuple[HybridGlobal, HybridShard]:
     """Bounded-staleness pass for ONE chain: shard sub-iterations WITHOUT
     the master sync (DESIGN.md §10).
@@ -570,7 +537,7 @@ def _hybrid_stale_body(
     gs_sweep = dataclasses.replace(gs, key=jax.random.fold_in(gs.key, 13))
     Z, Z_tail, tail_active, _, _ = _vmap_sub_iterations(
         X_shards, ss.Z, ss.Z_tail, ss.tail_active, gs_sweep, N_g, L,
-        backend, collapsed_backend, chol_refresh, k_live_pack,
+        backend, row_scan,
     )
     # stale passes don't touch gs — saturation and refreshes on them are
     # uncounted (the pass is explicitly non-exact; the counters stay
@@ -614,10 +581,11 @@ def build_hybrid_fns(
     """Build the hybrid iteration for ``spec``'s parallelism layout.
 
     This is hybrid.py's ONE construction entry point: every kernel knob
-    (``L``, ``backend``, ``collapsed_backend``, ``chol_refresh``,
-    ``sync``) and the parallelism layout (``chains`` x ``data``) are read
-    off ``spec`` (a ``repro.core.ibp.api.SamplerSpec`` or anything with
-    those attributes). ``mesh`` is required for shard_map layouts;
+    (``L``, ``backend``, ``sync``) and the parallelism layout (``chains``
+    x ``data``) are read off ``spec`` (a ``repro.core.ibp.api.SamplerSpec``
+    or anything with those attributes); the tail's row scan is
+    ``collapsed.tail_row_scan(spec.collapsed_backend, spec.chol_refresh)``,
+    built here once. ``mesh`` is required for shard_map layouts;
     ``data_axes`` may name several mesh axes (flattened into the P
     processors — the production dry-run path), ``chain_axes`` exactly one.
 
@@ -626,29 +594,28 @@ def build_hybrid_fns(
     (sum over a vmap axis vs. jax.lax.psum over mesh axes).
     """
     N_g = float(N_global)
+    row_scan = tail_row_scan(spec.collapsed_backend, spec.chol_refresh)
     if spec.chains in ("none", "vmap") and spec.data == "vmap":
-        return _build_vmap_fns(spec, hyp, N_g)
+        return _build_vmap_fns(spec, hyp, N_g, row_scan)
     if mesh is None:
         raise ValueError(
             f"layout chains={spec.chains!r} x data={spec.data!r} needs a "
             f"mesh; pass mesh= (build_sampler constructs one from the spec)"
         )
-    return _build_mesh_fns(spec, hyp, N_g, mesh, data_axes, chain_axes)
+    return _build_mesh_fns(spec, hyp, N_g, row_scan, mesh, data_axes,
+                           chain_axes)
 
 
-def _build_vmap_fns(spec, hyp, N_g: float) -> HybridFns:
+def _build_vmap_fns(spec, hyp, N_g: float, row_scan: Callable) -> HybridFns:
     """Single-device layouts: P shards simulated by vmap, optional chain
     axis vmapped OVER the full iteration (DESIGN.md §11)."""
     L, be = spec.L, spec.backend
-    cb, cr = spec.collapsed_backend, spec.chol_refresh
-    pk = spec.k_live_buckets == "on"
 
     def step_one(Xs, gs, ss):
-        return _hybrid_iteration_body(Xs, gs, ss, hyp, L, N_g, be, cb, cr,
-                                      pk)
+        return _hybrid_iteration_body(Xs, gs, ss, hyp, L, N_g, be, row_scan)
 
     def stale_one(Xs, gs, ss):
-        return _hybrid_stale_body(Xs, gs, ss, L, N_g, be, cb, cr, pk)
+        return _hybrid_stale_body(Xs, gs, ss, L, N_g, be, row_scan)
 
     if spec.chains == "vmap":
         # built ONCE as jit(vmap(...)) — a bare vmap-of-jit would re-trace
@@ -660,7 +627,7 @@ def _build_vmap_fns(spec, hyp, N_g: float) -> HybridFns:
     return HybridFns(step=jax.jit(step), stale=jax.jit(stale))
 
 
-def _build_mesh_fns(spec, hyp, N_g: float, mesh,
+def _build_mesh_fns(spec, hyp, N_g: float, row_scan: Callable, mesh,
                     data_axes: tuple[str, ...],
                     chain_axes: tuple[str, ...]) -> HybridFns:
     """shard_map layouts: data sharded over ``data_axes``
@@ -696,8 +663,6 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
     import numpy as np
 
     L, be = spec.L, spec.backend
-    cb, cr = spec.collapsed_backend, spec.chol_refresh
-    pk = spec.k_live_buckets == "on"
     sync = spec.sync
     chainful = spec.chains == "mesh"
     data_sharded = spec.data == "shardmap"
@@ -737,8 +702,7 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                     gs, key=jax.random.fold_in(gs.key, 13)
                 )
                 Z_p, Zt_p, ta, _, _ = shard_sub_iterations(
-                    X_p, Z_p, Zt_p, ta, gs_sweep, idx, N_g, L, be, cb, cr,
-                    pk,
+                    X_p, Z_p, Zt_p, ta, gs_sweep, idx, N_g, L, be, row_scan,
                 )
                 gs_out = dataclasses.replace(
                     gs, key=jax.random.fold_in(gs.key, 14)
@@ -749,7 +713,7 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                 ta = ta_p[0]  # (1, K_tail) local block -> (K_tail,)
                 idx = jax.lax.axis_index(data_axes)
                 Z_p, Zt_p2, ta, n_sat, n_ref = shard_sub_iterations(
-                    X_p, Z_p, Zt_p, ta, gs, idx, N_g, L, be, cb, cr, pk
+                    X_p, Z_p, Zt_p, ta, gs, idx, N_g, L, be, row_scan
                 )
                 with jax.named_scope("ibp_sync"):
                     # AR 1: the other shards wait here for p′'s tail
@@ -775,7 +739,7 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                 ta = ta_p[0]
                 idx = jax.lax.axis_index(data_axes)
                 Z_p, Zt_p2, ta, n_sat, n_ref = shard_sub_iterations(
-                    X_p, Z_p, Zt_p, ta, gs, idx, N_g, L, be, cb, cr, pk
+                    X_p, Z_p, Zt_p, ta, gs, idx, N_g, L, be, row_scan
                 )
                 with jax.named_scope("ibp_sync"):
                     K_max = Z_p.shape[1]
@@ -830,11 +794,10 @@ def _build_mesh_fns(spec, hyp, N_g: float, mesh,
                 ss_c = HybridShard(Z=Z_c, Z_tail=Zt_c, tail_active=ta_c)
                 if stale:
                     gs2, ss2 = _hybrid_stale_body(X_full, gs, ss_c, L, N_g,
-                                                  be, cb, cr, pk)
+                                                  be, row_scan)
                 else:
                     gs2, ss2 = _hybrid_iteration_body(X_full, gs, ss_c, hyp,
-                                                      L, N_g, be, cb, cr,
-                                                      pk)
+                                                      L, N_g, be, row_scan)
                 return gs2, ss2.Z, ss2.Z_tail, ss2.tail_active
 
             if data_sharded:

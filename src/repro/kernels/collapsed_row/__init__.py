@@ -1,11 +1,9 @@
 from .ops import collapsed_row_flip
 from .ref import collapsed_row_flip_ref
 from .fast import collapsed_row_flip_fast
-from .kernel import collapsed_row_flip_pallas
 
 __all__ = [
     "collapsed_row_flip",
     "collapsed_row_flip_ref",
     "collapsed_row_flip_fast",
-    "collapsed_row_flip_pallas",
 ]

@@ -18,7 +18,8 @@ exactness-preserving rewrites (DESIGN.md §12):
   indices of ``active_m``, in increasing order — identical decisions to
   the full-K scan, with the trip count K₊ instead of K_max. On CPU this
   is a dynamic-bound while_loop; on TPU lockstep SIMD makes packing
-  pointless, which is why the Pallas kernel keeps the full-K form.
+  pointless, which is why the fused tail kernel (``kernels/tail_scan``)
+  keeps the full-K form.
   Under occupancy-adaptive packing every input is already the K_live
   block (K here = the bucket size, not K_max); nothing changes — the
   recurrence is shape-generic and the block is ordered canonically.

@@ -6,10 +6,9 @@
   sampler's inner loop). The ``backend="ref"`` sampler uses this.
 * ``"packed"`` — the CPU-fast form: O(K) per bit (rss/rH carry) over the
   packed active columns only (dynamic-bound while_loop). The
-  ``backend="fast"`` sampler uses this.
-* ``"pallas"`` — the Pallas kernel, full-K mean-form like "jnp" (compiled
-  on TPU; ``interpret=True`` elsewhere, decided once via
-  ``kernels/_backend.py``). Selected by the sampler's ``backend="pallas"``.
+  ``backend="fast"`` sampler's XLA scan uses this; on a TPU the hybrid
+  tail runs the same recurrence inside the fused row-scan kernel
+  (``kernels/tail_scan``).
 
 No jit here: the caller (``core/ibp/collapsed.py``) traces this inside an
 already-jitted row scan, and ``flavor`` is static by construction.
@@ -23,13 +22,10 @@ per-bit moves stay O(K) without the per-row O(K²D) GEMM.
 """
 from __future__ import annotations
 
-from repro.kernels._backend import default_interpret
-
 from .fast import collapsed_row_flip_fast
-from .kernel import collapsed_row_flip_pallas
 from .ref import collapsed_row_flip_ref
 
-FLAVORS = ("jnp", "packed", "pallas")
+FLAVORS = ("jnp", "packed")
 
 
 def collapsed_row_flip(
@@ -39,15 +35,10 @@ def collapsed_row_flip(
     """Run the K-sequential bit-flip recurrence; returns (z, v, q, mean).
 
     ``G`` (optional) is the caller-carried H Hᵀ; only the ``packed``
-    flavor consumes it (the mean-form flavors never materialize G).
+    flavor consumes it (the mean-form oracle never materializes G).
     """
     if flavor not in FLAVORS:
         raise ValueError(f"flavor={flavor!r} not in {FLAVORS}")
-    if flavor == "pallas":
-        return collapsed_row_flip_pallas(
-            M, H, x_n, z, v, q, mean, u, m_minus, active_m, N, inv2s2,
-            interpret=default_interpret(),
-        )
     if flavor == "packed":
         return collapsed_row_flip_fast(
             M, H, x_n, z, v, q, mean, u, m_minus, active_m, N, inv2s2, G=G

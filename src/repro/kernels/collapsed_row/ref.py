@@ -14,8 +14,9 @@ with prior odds m_k / (N - m_k). Each step is O(K + D): the flip moves
 This is the INNER LOOP of the collapsed sampler — the fast
 ``backend="fast"`` row step (core/ibp/collapsed.py) carries (L, M, H)
 across rows with rank-one up/downdates and hands this recurrence the
-same arguments the O(K^3) oracle computes from scratch, so ref and
-kernel must agree bitwise given identical inputs.
+same arguments the O(K^3) oracle computes from scratch; the packed
+flavor (``fast.py``) must reproduce this recurrence's decisions given
+identical inputs.
 """
 from __future__ import annotations
 

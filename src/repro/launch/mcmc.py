@@ -16,14 +16,8 @@ Usage:
 Kernel knobs (all spec-validated; see DESIGN.md §12–§14):
 
   --backend jnp|pallas            uncollapsed Z sweep implementation
-  --collapsed-backend ref|fast|pallas
-                                  tail collapsed row step (default fast)
+  --collapsed-backend ref|fast    tail collapsed row step (default fast)
   --chol-refresh INT              fast-path exact-refactor cadence
-  --k-live-buckets on|off         occupancy-adaptive packing of the
-                                  collapsed carry (default on; off =
-                                  the same unified core pinned to the
-                                  top bucket B = K_max — bitwise the
-                                  historical unpacked carry)
   --K-tail INT                    in-flight tail features on p'
                                   (must be <= K_max)
   --k-tail-grow INT               adaptive K_tail: max automatic tail
@@ -92,22 +86,15 @@ def main(argv=None):
     ap.add_argument("--stale-sync", type=int, default=0,
                     help="bounded-staleness passes per iteration (non-exact)")
     ap.add_argument("--collapsed-backend", default="fast",
-                    choices=["ref", "fast", "pallas"],
+                    choices=["ref", "fast"],
                     help="tail collapsed row step (default: fast — the "
-                         "rank-one Cholesky carry, certified equivalent to "
-                         "ref by the PR-2 suite and CI soak). ref keeps the "
-                         "fresh O(K^3) factorization per row; pallas adds "
-                         "the Pallas bit-flip kernel on top of fast")
+                         "rank-one Cholesky carry, held to ref by "
+                         "tests/test_collapsed_fast.py; on a TPU the whole "
+                         "row scan is one fused kernel). ref keeps the "
+                         "fresh O(K^3) factorization per row")
     ap.add_argument("--chol-refresh", type=int, default=DEFAULT_REFRESH,
-                    help="exact-refactorization cadence of the fast/pallas "
+                    help="exact-refactorization cadence of the fast "
                          "collapsed backend (rows between refreshes)")
-    ap.add_argument("--k-live-buckets", default="on", choices=["on", "off"],
-                    help="occupancy-adaptive packing of the collapsed "
-                         "carry (DESIGN.md §14): on (default) runs the "
-                         "fast/pallas carry on the live K+ block (power-"
-                         "of-two buckets, G = HH^T carried rank-one); "
-                         "off keeps the unpacked K_max carry — exactly "
-                         "today's pre-packing behavior")
     ap.add_argument("--harvest-every", type=int, default=0,
                     help="SampleBank harvest cadence in iterations "
                          "(0 = off); chain-batched drivers harvest one "
@@ -139,7 +126,6 @@ def main(argv=None):
         sync=args.sync, stale_sync=args.stale_sync,
         collapsed_backend=args.collapsed_backend,
         chol_refresh=args.chol_refresh,
-        k_live_buckets=args.k_live_buckets,
         harvest_every=args.harvest_every,
         harvest_burn=args.harvest_burn,
         bank_path=args.bank_path,

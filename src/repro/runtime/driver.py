@@ -104,9 +104,9 @@ class DriverConfig:
     sync: str = "staged"       # "staged" | "fused" master sync (collective)
     overflow_every: int = 8    # overflow-detection cadence (host sync)
     k_tail_grow: int = 0       # adaptive K_tail: max tail doublings (0=off)
-    collapsed_backend: str = "fast"  # "ref" | "fast" | "pallas" tail step
-    chol_refresh: int = DEFAULT_CHOL_REFRESH  # "fast"/"pallas" cadence
-    k_live_buckets: str = "on"  # occupancy-adaptive packing (DESIGN.md §14)
+    collapsed_backend: str = "fast"  # "fast" | "ref" (oracle) tail step
+    chol_refresh: int = DEFAULT_CHOL_REFRESH  # "fast" refactor cadence
+    k_live_buckets: str = "on"  # "on" only, as SamplerSpec
     harvest_every: int = 0     # SampleBank harvest cadence (0 = off, §15)
     harvest_burn: float = 0.5  # burn-in fraction before harvesting
     bank_path: str = ""        # bank npz ("" = <ckpt_dir>/bank.npz)
@@ -525,8 +525,7 @@ class MCMCDriver:
         gs_ev = (gs if self.sampler.mesh is None
                  else jax.device_put(gs, jax.devices()[0]))
         # which path the tail's row scan took: the fused kernel or the scan
-        path = tail_path(self.spec.collapsed_backend,
-                         self.spec.k_live_buckets == "on")
+        path = tail_path(self.spec.collapsed_backend)
         if self._chain_axis:
             C = ss.Z.shape[0]
             Z = ss.Z.reshape(C, self.N, -1)

@@ -80,14 +80,6 @@ def test_unpacked_fast_sweep_matches_oracle_sweep(data, seed):
     assert np.isclose(float(a.sigma_x), float(b.sigma_x), rtol=1e-3)
 
 
-def test_pallas_sweep_matches_oracle_sweep(data):
-    a = _run(data, "ref", 16, sweeps=3, seed=0)
-    b = _run(data, "pallas", 16, sweeps=3, seed=0)
-    mism = _mismatch(a, b)
-    assert mism <= MISMATCH_BUDGET, f"{mism} bits diverged"
-    assert np.isclose(float(a.sigma_x), float(b.sigma_x), rtol=1e-3)
-
-
 def test_packed_sweep_bitwise_across_bucket_growth():
     """Cold start on rich data with a high alpha: births overflow the
     8-bucket MID-SWEEP, forcing repack-up + resume — decisions must stay

@@ -14,7 +14,6 @@ import sys
 import textwrap
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -55,9 +54,11 @@ INVALID_SPECS = [
     (dict(sync="fused", chains="mesh", data="vmap"), "fused"),
     (dict(backend="cuda"), "backend"),
     (dict(collapsed_backend="magic"), "collapsed_backend"),
+    (dict(collapsed_backend="pallas"), "collapsed_backend"),
     (dict(chol_refresh=0), "chol_refresh"),
     (dict(k_live_buckets="auto"), "k_live_buckets"),
     (dict(k_live_buckets=""), "k_live_buckets"),
+    (dict(k_live_buckets="off"), "k_live_buckets"),
     (dict(P=0), "P="),
     (dict(L=0), "L="),
     (dict(K_max=0), "K_max"),
@@ -126,25 +127,11 @@ def test_driverconfig_shim_maps_onto_spec():
     # the collapsed tail default is now the certified-equivalent fast path
     assert DriverConfig().collapsed_backend == "fast"
     assert SamplerSpec().collapsed_backend == "fast"
-    # occupancy-adaptive packing defaults on and maps through the shim
+    # the tail always carries G: "on" is the one value, through the shim
     assert DriverConfig().k_live_buckets == "on"
     assert SamplerSpec().k_live_buckets == "on"
-    assert as_spec(DriverConfig(k_live_buckets="off")).k_live_buckets == "off"
-
-
-def test_k_live_buckets_off_selects_unpacked_carry():
-    """k_live_buckets="off" keeps the pre-packing hybrid behavior: a
-    sampler built either way runs, and (since the full-width packed and
-    unpacked carries differ only in float path) both stay finite/sane."""
-    X, _, _ = cambridge_data(N=24, seed=2)
-    for mode in ("on", "off"):
-        spec = SamplerSpec(P=2, K_max=8, K_tail=4, K_init=2, L=2,
-                           k_live_buckets=mode)
-        s = build_sampler(spec, IBPHypers(), X)
-        gs, st = s.init(jax.random.key(0))
-        gs, st = s.step(gs, st)
-        assert np.isfinite(float(gs.sigma_x))
-        assert 0 <= int(jnp.sum(gs.active)) <= spec.K_max
+    with pytest.raises(ValueError, match="k_live_buckets"):
+        as_spec(DriverConfig(k_live_buckets="off"))
 
 
 def test_build_sampler_rejects_insufficient_devices():

@@ -161,13 +161,20 @@ def test_drift_probe_reads_a_perturbed_carry():
     assert want > 1e-2   # the monitor's default tolerance trips
 
 
-def test_tail_path_is_fused_only_on_tpu_for_packed_fast(monkeypatch):
-    assert collapsed.tail_path("fast", True) == (
-        "fused" if jax.default_backend() == "tpu" else "scan")
-    monkeypatch.setattr(collapsed, "on_tpu", lambda: True)
-    assert collapsed.tail_path("fast", True) == "fused"
-    for backend, pack in (("fast", False), ("ref", True), ("pallas", True)):
-        assert collapsed.tail_path(backend, pack) == "scan"
+@pytest.mark.parametrize("platform,backend,path", [
+    ("tpu", "fast", "fused"), ("tpu", "ref", "scan"),
+    ("cpu", "fast", "scan"), ("cpu", "ref", "scan")])
+def test_tail_path_is_fused_only_on_tpu_for_packed_fast(monkeypatch,
+                                                        platform, backend,
+                                                        path):
+    """``tail_row_scan`` hands the hybrid the row scan ``tail_path``
+    names: the fused kernel only on a TPU, and only for ``fast``."""
+    monkeypatch.setattr(collapsed, "on_tpu", lambda: platform == "tpu")
+    assert collapsed.tail_path(backend) == path
+    scan = collapsed.tail_row_scan(backend)
+    assert (scan.func is fused_tail_scan) == (path == "fused")
+    with pytest.raises(ValueError, match="backend"):
+        collapsed.tail_row_scan("pallas")
 
 
 HYBRID = dict(P=5, L=2, K_max=12, K_tail=4, K_init=1, alpha=3.0,

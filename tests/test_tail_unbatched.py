@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro.core.ibp import IBPHypers, SamplerSpec, build_sampler
-from repro.core.ibp import hybrid
+from repro.core.ibp import collapsed, hybrid
 
 N_ITERS = 8
 # a small paper-like instance: P=5 shards of 20 rows of six strong
@@ -42,9 +42,9 @@ def _oracle_sub(Xs, gs, ss, spec, N_g):
     over the P simulated shards."""
     sub = partial(
         hybrid.shard_sub_iterations, N_global=N_g, L=spec.L,
-        backend=spec.backend, collapsed_backend=spec.collapsed_backend,
-        chol_refresh=spec.chol_refresh,
-        k_live_pack=spec.k_live_buckets == "on",
+        backend=spec.backend,
+        row_scan=collapsed.tail_row_scan(spec.collapsed_backend,
+                                         spec.chol_refresh),
     )
     return jax.vmap(sub, in_axes=(0, 0, 0, 0, None, 0))(
         Xs, ss.Z, ss.Z_tail, ss.tail_active, gs, jnp.arange(Xs.shape[0]))
@@ -79,12 +79,13 @@ def _leaves(gs, ss):
 
 
 @pytest.mark.parametrize("chains", ["none", "vmap"])
-@pytest.mark.parametrize("k_live", ["on", "off"])
+@pytest.mark.parametrize("collapsed_backend", ["ref", "fast"])
 @pytest.mark.parametrize("fn", ["step", "stale"])
-def test_vmap_layout_matches_batched_tail_bitwise(X, fn, k_live, chains):
+def test_vmap_layout_matches_batched_tail_bitwise(X, fn, collapsed_backend,
+                                                  chains):
     """``stale`` runs a stale pass before every step, so the pass's own
     tail (kept, not cleared) is compared and then promoted by the step."""
-    kw = dict(SPEC, k_live_buckets=k_live, chains=chains)
+    kw = dict(SPEC, collapsed_backend=collapsed_backend, chains=chains)
     if chains == "vmap":
         kw["n_chains"] = 2
     spec = SamplerSpec(**kw)
@@ -111,7 +112,9 @@ def test_vmap_layout_matches_batched_tail_bitwise(X, fn, k_live, chains):
         births += int(np.sum(np.asarray(jnp.sum(gs.active, axis=-1))
                              > k_before))
     # the trajectory exercised what the change touches: a tail on a
-    # shard other than 0, births promoted out of it, and refreshes
+    # shard other than 0, births promoted out of it, and refreshes of
+    # the fast carry (the ref oracle factorizes every row afresh)
     assert np.any(np.concatenate([np.ravel(p) for p in pps]) != 0)
     assert births > 0
-    assert np.all(np.asarray(gs.tail_refresh) > 0)
+    assert np.all(np.asarray(gs.tail_refresh) > 0) == (
+        collapsed_backend == "fast")

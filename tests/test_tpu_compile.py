@@ -1,4 +1,4 @@
-"""The four Pallas kernels compile for a described TPU v5e chip.
+"""The Pallas kernels compile for a described TPU v5e chip.
 
 Interpret mode runs the kernel bodies on the CPU and cannot see what the
 TPU compiler refuses: scalar stores to VMEM, blocks not aligned to the
@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.collapsed_row.kernel import collapsed_row_flip_pallas
 from repro.kernels.feature_stats import feature_stats_core
 from repro.kernels.gaussian_sse import gaussian_sse_core
 from repro.kernels.gibbs_flip import gibbs_flip_core
@@ -74,18 +73,14 @@ def _kernel_case(name, N, D, K):
     if name == "feature_stats":
         fn = lambda X, Z: feature_stats_core(X, Z, interpret=False)
         return fn, [(N, D), (N, K)]
-    if name == "gaussian_sse":
-        fn = lambda X, Z, A, act: gaussian_sse_core(X, Z, A, act,
-                                                    interpret=False)
-        return fn, [(N, D), (N, K), (K, D), (K,)]
-    fn = lambda *a: collapsed_row_flip_pallas(*a, interpret=False)
-    return fn, [(K, K), (K, D), (D,), (K,), (K,), (), (D,), (K,), (K,),
-                (K,), (), ()]
+    fn = lambda X, Z, A, act: gaussian_sse_core(X, Z, A, act,
+                                                interpret=False)
+    return fn, [(N, D), (N, K), (K, D), (K,)]
 
 
 @pytest.mark.parametrize("size", sorted(SIZES))
 @pytest.mark.parametrize("name", ["gibbs_flip", "feature_stats",
-                                  "gaussian_sse", "collapsed_row"])
+                                  "gaussian_sse"])
 def test_kernel_compiles_for_v5e(name, size, one_chip):
     fn, shapes = _kernel_case(name, *SIZES[size])
     compiled = _lower(fn, shapes, one_chip).compile()
@@ -148,10 +143,11 @@ def test_fused_tail_carries_its_scope_for_v5e(one_chip, monkeypatch):
         sigma_x=f32(), sigma_a=f32(), key=key, p_prime=i32, it=i32,
         overflow=i32, tail_sat=i32, tail_refresh=i32)
 
+    row_scan = collapsed.tail_row_scan("fast", 64)
+
     def tail(X_p, Z, Z_tail, tail_active, gs, key):
         return hybrid._tail_sub_iteration(
-            X_p, Z, Z_tail, tail_active, gs, 900.0, key,
-            collapsed_backend="fast", chol_refresh=64, k_live_pack=True)
+            X_p, Z, Z_tail, tail_active, gs, 900.0, key, row_scan)
 
     hlo = jax.jit(tail).lower(f32(n, D), f32(n, K_max), f32(n, K_tail),
                               f32(K_tail), gs, key).compile().as_text()
